@@ -279,13 +279,52 @@ class LookaheadEngine:
 def reference_decode(fns: StepFns, prompt: Sequence[int],
                      max_new_tokens: Optional[int] = None,
                      eos_id: int = -1, pad_id: int = 0,
-                     params: Optional[SamplingParams] = None) -> List[int]:
-    """Plain step-by-step decoding through the *same* device functions
-    (width-1 step with an empty draft), honoring the request's own
-    ``SamplingParams``.  Ground truth for lossless tests."""
-    cfg = LookaheadConfig(strategy="none", decoding_length=0)
-    engine = LookaheadEngine(fns, cfg, eos_id=eos_id)
-    return engine.generate(prompt, max_new_tokens, params=params).tokens
+                     params: Optional[SamplingParams] = None,
+                     lanes: Optional[int] = None,
+                     like: Optional[GenStats] = None) -> List[int]:
+    """Plain step-by-step decoding through the *same* device functions,
+    honoring the request's own ``SamplingParams``.  Ground truth for
+    lossless tests.
+
+    ``lanes=None`` steps width-1 trees on one lane.  ``lanes=n`` steps
+    root-only trees at the session's full tree width on ``n`` lanes — the
+    very executables an ``n``-lane engine of this session runs — and
+    ``like`` (a served request's stats) admits the prompt through the
+    program that served it: the first cohort's batched ``prefill``,
+    ``prefill_into_slot``, or ``prefill_suffix`` after the same prefix-cache
+    hit (the cached prefix is served first to seed the cache).  On an
+    accelerator two programs of different shapes or algorithms may round
+    differently in bf16, so that is the reference a served engine is held
+    to bit for bit there."""
+    if lanes is None:
+        if like is not None:
+            raise ValueError("like= replays a served admission; it needs "
+                             "lanes= (the served engine's lane count)")
+        cfg = LookaheadConfig(strategy="none", decoding_length=0)
+        engine = LookaheadEngine(fns, cfg, eos_id=eos_id)
+        return engine.generate(prompt, max_new_tokens, params=params).tokens
+    from repro.serving.scheduler import ContinuousScheduler
+    p = _per_request_params(fns, 1, max_new_tokens, params)[0]
+    cached = like.cached_prompt_tokens if like is not None else 0
+    # a zero draft budget in the default namespace: every tree is the root
+    # alone, padded to the full width
+    sched = ContinuousScheduler(fns, LookaheadConfig(), lanes=lanes,
+                                eos_id=eos_id, prefill_len=fns.prefill_len,
+                                draft_budget_caps={"": 0},
+                                prefix_cache=cached > 0)
+    seed = None
+    if cached:
+        seed = prompt[:cached]      # retires into the prefix cache
+    elif like is not None and like.prefill == "prefill_into_slot":
+        seed = prompt[:1]           # builds the cache: admission mid-flight
+    if seed is not None:
+        sched.submit_request(Request(prompt=list(seed),
+                                     params=SamplingParams(max_new_tokens=1)))
+        sched.run()
+    handle = sched.submit_request(Request(
+        prompt=list(prompt), params=dataclasses.replace(p, draft=None)))
+    sched.run()
+    return handle.result().tokens
 
 
 __all__ = ["LookaheadEngine", "StepFns", "GenStats", "RequestResult",

@@ -226,6 +226,10 @@ class GenStats:
     host_syncs: int = 0
     # prompt tokens served from the prefix cache (prefill compute skipped)
     cached_prompt_tokens: int = 0
+    # the StepFns member that prefilled the prompt (scheduler runs only):
+    # "prefill" (first cohort), "prefill_into_slot" or "prefill_suffix"
+    # (prefix-cache hit) — reference_decode(like=...) replays it
+    prefill: str = ""
 
     @property
     def edl(self) -> float:

@@ -24,9 +24,8 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.distributed.sharding import active_mesh, axis_size
+from repro.distributed.sharding import active_mesh
 from repro.models.layers import NEG_INF
 
 
@@ -71,7 +70,7 @@ def make_flash_attend(mesh: Mesh, cache_lens: jax.Array,
 
         fn = functools.partial(_local_attend, seq_axes=seq_axes,
                                T=T, scale=dh ** -0.5, score_f32=score_f32)
-        out, kc, vc = shard_map(
+        out, kc, vc = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(ba, None, h_ax, None),      # q
                       P(ba, None, h_ax, None),      # k_new
@@ -83,7 +82,7 @@ def make_flash_attend(mesh: Mesh, cache_lens: jax.Array,
             out_specs=(P(ba, None, h_ax, None),
                        P(ba, sa, h_ax, None),
                        P(ba, sa, h_ax, None)),
-            check_rep=False,
+            check_vma=False,
         )(q, k_new, v_new, k_cache, v_cache, cache_lens, tree_mask)
         return out, kc, vc
 
@@ -106,7 +105,7 @@ def _local_attend(q, k_new, v_new, k_c, v_c, cache_lens, tree_mask, *,
     # global offset of this shard's KV rows
     idx = jnp.zeros((), jnp.int32)
     for a in seq_axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     offset = idx * Sl
 
     # scatter the new draft KV rows that land in this shard.  NB: negative

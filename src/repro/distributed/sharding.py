@@ -133,17 +133,5 @@ def named_sharding(mesh: Mesh, logical_axes: Sequence[Optional[str]],
     return NamedSharding(mesh, logical_spec(logical_axes, shape, mesh, rules))
 
 
-def axis_size(name: str) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    ``jax.lax.axis_size`` only exists in newer jax releases; on older ones a
-    psum of the Python literal 1 takes jax's constant fast path and returns
-    the axis size as a static int (shape-safe for reshapes).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 __all__ = ["ShardingRules", "DEFAULT_RULES", "sharding_ctx", "constrain",
-           "active_mesh", "logical_spec", "named_sharding", "axis_size"]
+           "active_mesh", "logical_spec", "named_sharding"]

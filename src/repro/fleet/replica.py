@@ -4,22 +4,32 @@ The router and gossip coordinator speak to replicas through a small
 message-shaped API (submit / step / drain / queue_depth / result / stats /
 draft-state ops) so the same fleet code drives two execution modes:
 
-  * ``mode="inproc"`` — the engine lives in this process.  Deterministic
-    and cheap: tests and CI smokes run whole fleets in one interpreter,
-    and bit-identity against a single-replica reference is exact.
+  * ``mode="inproc"`` — the engine lives in this process.  This is the
+    deployment shape on an accelerator host: one process drives one
+    replica per chip, each pinned with ``device=`` — the replica builds
+    its engine and runs every command under ``jax.default_device(device)``,
+    so caches and step inputs land on that chip; the builder places its
+    weights there (``jax.device_put(params, device)``).  Deterministic:
+    tests and CI smokes run whole fleets in one interpreter, and
+    bit-identity against a single-replica reference is exact.
   * ``mode="subprocess"`` — the engine lives in a spawned worker process
     (its own device context), commands travel over a pipe.  The builder
     callable must be picklable (a module-level function or
     ``functools.partial`` of one); the engine is constructed inside the
-    child, so device buffers never cross the process boundary.
+    child, so device buffers never cross the process boundary.  CPU
+    backends only: a chip belongs to one process, and a parent that has
+    touched JAX on a TPU host already holds it.
 
 Results and stats cross the boundary as plain dicts — the same shapes the
 in-process mode returns, so callers never branch on the mode.
 """
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
 from typing import Any, Callable, Dict, List, Optional, Sequence
+
+import jax
 
 from repro.core.request import Request, RequestResult, SamplingParams
 
@@ -105,17 +115,28 @@ class EngineReplica:
     """One engine of a fleet, addressable through replica commands."""
 
     def __init__(self, builder: Callable[[], Any], *,
-                 replica_id: str = "r0", mode: str = "inproc"):
+                 replica_id: str = "r0", mode: str = "inproc",
+                 device: Optional[jax.Device] = None):
         if mode not in ("inproc", "subprocess"):
             raise ValueError(f"mode={mode!r}: expected 'inproc' or "
                              "'subprocess'")
+        if mode == "subprocess" and device is not None:
+            raise ValueError("device= pins an in-process replica; a "
+                             "subprocess replica owns its own devices")
+        if mode == "subprocess" and jax.default_backend() == "tpu":
+            raise ReplicaError(
+                "subprocess replicas cannot share a TPU host: this process "
+                "already holds the chips.  Drive one in-process replica "
+                "per chip instead (mode='inproc', device=jax.devices()[i])")
         self.replica_id = str(replica_id)
         self.mode = mode
+        self.device = device
         self.engine = None
         self._conn = None
         self._proc = None
         if mode == "inproc":
-            self.engine = builder()
+            with self._on_device():
+                self.engine = builder()
         else:
             ctx = mp.get_context("spawn")   # fresh interpreter: device-safe
             self._conn, child = ctx.Pipe()
@@ -126,6 +147,10 @@ class EngineReplica:
             self._check(self._conn.recv())  # construction ack
 
     # ------------------------------------------------------------- plumbing
+    def _on_device(self):
+        return (jax.default_device(self.device) if self.device is not None
+                else contextlib.nullcontext())
+
     def _check(self, reply):
         status, value = reply
         if status != "ok":
@@ -134,7 +159,8 @@ class EngineReplica:
 
     def _call(self, cmd: str, *args):
         if self.engine is not None:
-            return _dispatch(self.engine, cmd, args)
+            with self._on_device():
+                return _dispatch(self.engine, cmd, args)
         self._conn.send((cmd, args))
         return self._check(self._conn.recv())
 

@@ -17,9 +17,11 @@ therefore places requests by *namespace affinity*:
     acceptance for admission latency — gossip (repro.fleet.gossip) warms
     the spill target so repeated spills stop being cold.
 
-Routing never affects outputs: every replica runs the same verifier, so a
-request generates bit-identical tokens wherever it lands (I1) — the router
-is purely a throughput/latency policy.
+Routing never affects outputs on the CPU: every replica runs the same
+verifier, so a request generates bit-identical tokens wherever it lands
+(I1) — the router is purely a throughput/latency policy.  On a TPU in bf16
+the program that admitted a request (first-cohort, slot or prefix-hit
+prefill) also sets its bits, and routing changes which one does.
 """
 from __future__ import annotations
 

@@ -34,8 +34,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)             # (bq*G, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)          # (bk, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)                # (bk, dh)
+    v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     # causal mask from absolute positions
@@ -66,7 +66,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_prefill_grouped(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           block_q: int = 256, block_k: int = 512,
                           interpret: bool = False) -> jax.Array:
-    """q (B, K, S·G, dh) grouped causal self-attention; k/v (B, S, K, dh)."""
+    """q (B, K, S·G, dh) grouped causal self-attention; k/v (B, S, K·dh)
+    head-flattened view (kv head h is the 128-lane column block h)."""
     B, K, SG, dh = q.shape
     S = k.shape[1]
     g = SG // S
@@ -81,10 +82,8 @@ def flash_prefill_grouped(q: jax.Array, k: jax.Array, v: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1, 1, block_q * g, dh),
                          lambda b, h, qi, kj: (b, h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, qi, kj: (b, kj, h, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, qi, kj: (b, kj, h, 0)),
+            pl.BlockSpec((1, block_k, dh), lambda b, h, qi, kj: (b, kj, h)),
+            pl.BlockSpec((1, block_k, dh), lambda b, h, qi, kj: (b, kj, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q * g, dh),
                                lambda b, h, qi, kj: (b, h, qi, 0)),
@@ -119,8 +118,8 @@ def _kernel_tri(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     q_pos = qi * block + jax.lax.broadcasted_iota(
@@ -171,15 +170,15 @@ def flash_prefill_grouped_tri(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     def kmap(b, h, t):
         _, kj = _tri_qi(t)
-        return (b, kj, h, 0)
+        return (b, kj, h)
 
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block * g, dh), qmap),
-            pl.BlockSpec((1, block, 1, dh), kmap),
-            pl.BlockSpec((1, block, 1, dh), kmap),
+            pl.BlockSpec((1, block, dh), kmap),
+            pl.BlockSpec((1, block, dh), kmap),
         ],
         out_specs=pl.BlockSpec((1, 1, block * g, dh), qmap),
         out_shape=jax.ShapeDtypeStruct((B, K, SG, dh), q.dtype),

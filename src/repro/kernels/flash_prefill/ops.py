@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tree_attention.ops import default_interpret
+
 from .flash_prefill import flash_prefill_grouped, flash_prefill_grouped_tri
 from .ref import flash_prefill_ref
 
@@ -23,7 +25,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   ) -> jax.Array:
     """q (B, S, H, dh); k/v (B, S, K, dh) → causal attention (B, S, H, dh)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return _flash_prefill(q, k, v, block_q=block_q, block_k=block_k,
                           interpret=interpret, triangular=triangular)
 
@@ -56,6 +58,8 @@ def _flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
     qg = qg * ((dh_p / dh) ** 0.5)       # kernel scales by padded dh
+    k = k.reshape(B, S_pad, K * dh_p)     # free head-flattened view
+    v = v.reshape(B, S_pad, K * dh_p)
     if triangular:
         out = flash_prefill_grouped_tri(qg, k, v, block=min(bq, bk),
                                         interpret=interpret)

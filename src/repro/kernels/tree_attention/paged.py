@@ -12,8 +12,11 @@ per-lane cache — the gather that the dense paged backend does with
 Grid = (B, K, blocks_per_lane); the block axis is innermost/sequential and
 carries (m, l, acc) scratch in VMEM.  Unallocated table entries point at the
 reserved NULL block 0 — their rows are masked out, so the wasted DMA is the
-only cost of fixed shapes (I2).  On TPU ``block_size`` must be a sublane
-multiple (8 for f32); interpret mode (any non-TPU platform) takes any size.
+only cost of fixed shapes (I2).  The pool is read through a free
+(n_blocks, block_size, K*dh) view and the mask arrives as whole
+(Tp, block_size) int32 tiles, the same layout as the dense kernel.  On TPU
+``block_size`` must be a sublane multiple (16 for bf16); interpret mode
+(any non-TPU platform) takes any size.
 """
 from __future__ import annotations
 
@@ -24,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ops import _pad_to, default_interpret
+from .ops import (default_interpret, group_queries, kv_view, mask_tiles,
+                  ungroup_out)
 from .tree_attention import _kernel, _vmem
 
 
@@ -39,19 +43,20 @@ def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
 def paged_tree_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
                                  block_tables: jax.Array, mask: jax.Array, *,
                                  interpret: bool = False) -> jax.Array:
-    """q (B, K, TG, dh); k/v (n_blocks, block_size, K, dh);
-    block_tables (B, blocks_per_lane) int32; mask (B, T, S_virtual) with
-    S_virtual = blocks_per_lane * block_size and T = TG // G.
-    Returns (B, K, TG, dh).  dh should be a multiple of 128 (pad upstream).
+    """q (B, K, G*Tp, dh) group-major; k/v (n_blocks, block_size, K*dh)
+    head-flattened pool view; block_tables (B, blocks_per_lane) int32;
+    mask (B, blocks_per_lane, Tp, block_size) int32 tiles.
+    Returns (B, K, G*Tp, dh).  dh should be a multiple of 128 (pad
+    upstream).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     B, K, TG, dh = q.shape
-    n_blocks, bs = k.shape[0], k.shape[1]
+    bs = k.shape[1]
     bpl = block_tables.shape[1]
-    T = mask.shape[1]
-    assert mask.shape[2] == bpl * bs, (mask.shape, bpl, bs)
-    g = TG // T
+    Tp = mask.shape[2]
+    assert mask.shape[1] == bpl and mask.shape[3] == bs, (mask.shape, bpl)
+    g = TG // Tp
     grid = (B, K, bpl)
     kernel = functools.partial(_paged_kernel, scale=dh ** -0.5, g=g,
                                n_blocks=bpl)
@@ -60,11 +65,9 @@ def paged_tree_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, TG, dh), lambda b, h, j, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda b, h, j, bt: (bt[b, j], 0,
-                                                              h, 0)),
-            pl.BlockSpec((1, bs, 1, dh), lambda b, h, j, bt: (bt[b, j], 0,
-                                                              h, 0)),
-            pl.BlockSpec((1, T, bs), lambda b, h, j, bt: (b, 0, j)),
+            pl.BlockSpec((1, bs, dh), lambda b, h, j, bt: (bt[b, j], 0, h)),
+            pl.BlockSpec((1, bs, dh), lambda b, h, j, bt: (bt[b, j], 0, h)),
+            pl.BlockSpec((1, 1, Tp, bs), lambda b, h, j, bt: (b, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, TG, dh),
                                lambda b, h, j, bt: (b, h, 0, 0)),
@@ -100,22 +103,13 @@ def paged_tree_attention(q: jax.Array, k_cache: jax.Array,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_tree_attention(q, k_cache, v_cache, block_tables, mask, *,
                           interpret: bool):
-    B, T, H, dh = q.shape
-    K = k_cache.shape[2]
-    G = H // K
-    qg = q.reshape(B, T, K, G, dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, K, T * G, dh)
-    dh_p = -(-dh // 128) * 128
-    qg = _pad_to(qg, 3, 128)
-    kp = _pad_to(k_cache, 3, 128)
-    vp = _pad_to(v_cache, 3, 128)
-    # scale uses padded dh inside the kernel; compensate so logits match
-    scale_fix = (dh_p / dh) ** 0.5
-    out = paged_tree_attention_grouped(qg * scale_fix, kp, vp,
-                                       block_tables.astype(jnp.int32), mask,
-                                       interpret=interpret)
-    out = out[..., :dh].reshape(B, K, T, G, dh).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, T, H, dh)
+    T, dh = q.shape[1], q.shape[3]
+    K, bs = k_cache.shape[2], k_cache.shape[1]
+    out = paged_tree_attention_grouped(
+        group_queries(q, K), kv_view(k_cache), kv_view(v_cache),
+        block_tables.astype(jnp.int32), mask_tiles(mask, bs),
+        interpret=interpret)
+    return ungroup_out(out, T, dh)
 
 
 __all__ = ["paged_tree_attention", "paged_tree_attention_grouped"]

@@ -10,12 +10,18 @@ kernel streams KV blocks HBM→VMEM with an online-softmax accumulator, so the
 TPU mapping (vs. the paper's A100 version):
   * grid = (B, K, S/block_s); the S axis is the innermost, sequential
     dimension, carrying (m, l, acc) scratch in VMEM across iterations,
-  * q rows for one kv-head group = T·G ≤ 128·G — padded to an MXU-aligned
-    row count; dh padded to a multiple of 128 lanes by ops.py,
-  * the tree mask enters as a (T, S) boolean, blocked (T, block_s) per grid
-    step — ancestor-closure for the draft region, causal for the cache
-    region (built by ops.py / the serving layer),
-  * block_s multiple of 128; masked-out blocks contribute zeros (exp(-inf)).
+  * q rows for one kv-head group are laid out group-major, G blocks of
+    Tp = T padded to a sublane multiple (8), so the (Tp, block_s) mask tile
+    broadcasts over the groups with a tile-aligned reshape; dh is padded to
+    a multiple of 128 lanes by ops.py,
+  * the cache is read through a free (B, S, K*dh) view: a kv head is the
+    128-lane column block ``h`` of a (block_s, dh) tile, so no block has a
+    squeezed second-minor dimension (Mosaic refuses a (1, dh) head slice),
+  * the tree mask enters as int32 tiles (B, S/block_s, Tp, block_s) —
+    ancestor-closure for the draft region, causal for the cache region
+    (built by ops.py / the serving layer); each tile is a whole array
+    trailing dim pair, so any block_s the cache allows is legal,
+  * masked-out blocks contribute zeros (exp(-inf)).
 """
 from __future__ import annotations
 
@@ -38,13 +44,15 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (TG, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (bs, dh)
+    q = q_ref[0, 0].astype(jnp.float32)            # (G*Tp, dh)
+    k = k_ref[0].astype(jnp.float32)               # (bs, dh)
+    v = v_ref[0].astype(jnp.float32)               # (bs, dh)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    mask = mask_ref[0]                             # (T, bs) bool
-    mask = jnp.repeat(mask, g, axis=0)             # (TG, bs)
+    tm = mask_ref[0, 0]                            # (Tp, bs) int32
+    tp, bs = tm.shape
+    tm = jnp.broadcast_to(tm[None], (g, tp, bs)).reshape(g * tp, bs)
+    mask = tm != 0                                 # (G*Tp, bs)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[:, :1]                          # (TG, 1)
@@ -67,16 +75,18 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def tree_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
                            mask: jax.Array, *, block_s: int = 512,
                            interpret: bool = False) -> jax.Array:
-    """q (B, K, TG, dh); k/v (B, S, K, dh); mask (B, T, S) with T = TG // G.
+    """q (B, K, G*Tp, dh) group-major; k/v (B, S, K*dh) head-flattened
+    cache view; mask (B, S/block_s, Tp, block_s) int32 tiles (nonzero =
+    attend).  Returns (B, K, G*Tp, dh).
 
-    Returns (B, K, TG, dh).  S must be a multiple of block_s; dh should be a
-    multiple of 128 and TG a multiple of 8 (pad in ops.py).
+    S must be a multiple of block_s; dh should be a multiple of 128 and Tp
+    a multiple of 8 (ops.py pads and builds the tiles).
     """
     B, K, TG, dh = q.shape
     S = k.shape[1]
-    T = mask.shape[1]
-    g = TG // T
-    assert S % block_s == 0, (S, block_s)
+    Tp = mask.shape[2]
+    g = TG // Tp
+    assert S % block_s == 0 and k.shape[2] == K * dh, (k.shape, block_s)
     n_blocks = S // block_s
     grid = (B, K, n_blocks)
     kernel = functools.partial(_kernel, scale=dh ** -0.5, g=g,
@@ -86,9 +96,9 @@ def tree_attention_grouped(q: jax.Array, k: jax.Array, v: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, TG, dh), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, dh), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, block_s, 1, dh), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, T, block_s), lambda b, h, j: (b, 0, j)),
+            pl.BlockSpec((1, block_s, dh), lambda b, h, j: (b, j, h)),
+            pl.BlockSpec((1, block_s, dh), lambda b, h, j: (b, j, h)),
+            pl.BlockSpec((1, 1, Tp, block_s), lambda b, h, j: (b, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, TG, dh), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, TG, dh), q.dtype),

@@ -16,13 +16,18 @@ sampled traffic (distinct temperatures/seeds) inside the same lane pool, and
 ``--cancel-every N`` cancels every Nth request mid-flight through its
 ``RequestHandle`` — both exercises of the production API surface.
 
-On real hardware drop --smoke to load the full config (weights from
---ckpt-dir via training.checkpoint) onto the production mesh.
+Without --smoke the full config is served from one device (random weights,
+or --ckpt-dir via training.checkpoint); serve builds no mesh.
+``--replicas N`` drives N in-process engines behind the fleet router, one
+per chip (replica i on ``jax.devices()[i % n]``).  The persistent
+compilation cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
+``<repo>/.jax_cache`` (repro.launch.compile_cache).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import List
 
@@ -33,6 +38,7 @@ from repro import configs as cfgreg
 from repro.core import (DraftPolicy, LookaheadEngine, Request,
                         SamplingParams)
 from repro.core.draft_sources import available_sources
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import attention as attn_backends
 from repro.models import transformer as tx
 from repro.serving.api import EngineConfig, build_engine
@@ -187,6 +193,7 @@ def main() -> None:
                     help="re-run the fleet workload on one reference "
                          "engine and assert bit-identical outputs")
     args = ap.parse_args()
+    enable_compile_cache()
 
     def _ns_map(spec, cast):
         if not spec:
@@ -468,11 +475,16 @@ def _run_fleet(args, ecfg, cfg, params, reqs, lane_shares) -> None:
 
     from repro.fleet import EngineReplica, FleetRouter, GossipCoordinator
 
-    def _builder():
-        return build_engine(ecfg, cfg, params)
+    def _builder(device):
+        return build_engine(ecfg, cfg, jax.device_put(params, device))
 
-    replicas = [EngineReplica(_builder, replica_id=f"r{i}")
-                for i in range(args.replicas)]
+    # one replica per chip, round-robin over the devices this process holds
+    devices = jax.devices()
+    replicas = []
+    for i in range(args.replicas):
+        dev = devices[i % len(devices)]
+        replicas.append(EngineReplica(functools.partial(_builder, dev),
+                                      replica_id=f"r{i}", device=dev))
     if args.warm_state and os.path.exists(args.warm_state):
         for rep in replicas:
             rep.load_draft_state(args.warm_state)
@@ -526,7 +538,7 @@ def _run_fleet(args, ecfg, cfg, params, reqs, lane_shares) -> None:
         print(f"acceptance [{ns or '<default>'}]: {'   '.join(cells)}")
 
     if args.verify_fleet:
-        single = _builder()
+        single = build_engine(ecfg, cfg, params)
         handles = [single.submit(Request(prompt=list(r.prompt),
                                          params=r.params)) for r in reqs]
         single.run()

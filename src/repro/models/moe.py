@@ -19,9 +19,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-from repro.distributed.sharding import axis_size
 
 
 def router_topk(x: jax.Array, w_router: jax.Array, top_k: int
@@ -102,7 +99,7 @@ def moe_local(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     """
     n, D = x.shape
     if ep_axis is not None:
-        ep = axis_size(ep_axis)
+        ep = jax.lax.axis_size(ep_axis)
         E = w_gate.shape[0] * ep      # global expert count
     else:
         ep = 1
@@ -147,12 +144,12 @@ def moe_ep(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     fn = functools.partial(moe_local, top_k=top_k,
                            capacity_factor=capacity_factor, act=act,
                            ep_axis="model")
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(dp_axes, None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=P(dp_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(x, w_router, w_gate, w_up, w_down)
     return out[:N] if pad else out
 

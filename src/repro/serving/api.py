@@ -78,7 +78,8 @@ class EngineConfig:
     # radix-tree prefix caching over the paged pool (DESIGN.md §Prefix
     # cache): requests whose prompt prefix is already resident skip that
     # portion of prefill via refcounted copy-on-write block sharing.
-    # Outputs stay bit-identical to the uncached path.  prefix_cache_blocks
+    # Outputs stay bit-identical to the uncached path (on a TPU: to a
+    # reference replaying the hit, DESIGN.md I1).  prefix_cache_blocks
     # caps the tree's resident blocks (None = bounded by pool pressure).
     prefix_cache: bool = False
     prefix_cache_blocks: Optional[int] = None

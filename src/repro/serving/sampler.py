@@ -21,7 +21,6 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.sharding import active_mesh
 
@@ -53,9 +52,9 @@ def _sharded_argmax(logits: jax.Array) -> jax.Array:
         w = jnp.argmax(vs, axis=0)
         return jnp.take_along_axis(gs, w[None], axis=0)[0]
 
-    return shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                      in_specs=P(ba, None, "model"),
-                     out_specs=P(ba, None), check_rep=False)(logits)
+                     out_specs=P(ba, None), check_vma=False)(logits)
 
 
 def choose_tokens(logits: jax.Array, pred_positions: jax.Array,

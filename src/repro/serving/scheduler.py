@@ -783,7 +783,9 @@ class ContinuousScheduler:
                     self.cache, chosen = fns.prefill_suffix(
                         self.cache, lane, suffix, n_cached,
                         lane_params=self._lane_params_one(rs.params))
+                    rs.stats.prefill = "prefill_suffix"
                 else:
+                    rs.stats.prefill = "prefill_into_slot"
                     toks = np.full((1, self.prefill_len), fns.pad_id,
                                    dtype=np.int32)
                     toks[0, :len(rs.prompt)] = np.asarray(rs.prompt,
@@ -836,6 +838,7 @@ class ContinuousScheduler:
         for lane, rs in enumerate(cohort):
             rs.lane = lane
             rs.admit_t = now
+            rs.stats.prefill = "prefill"
             self._set_lane_params(lane, rs.params)
             self._observe_prompt(rs)
             toks[lane, :len(rs.prompt)] = np.asarray(rs.prompt,

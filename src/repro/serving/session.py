@@ -68,8 +68,13 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                      kv_layout: Optional[str] = None,
                      block_size: Optional[int] = None,
                      n_blocks: Optional[int] = None) -> StepFns:
-    """Jitted prefill / prefill_into_slot / tree_step / commit closures over
+    """Jitted prefill / prefill_into_slot / tree_step / commit steps over
     ``params``.
+
+    Every jitted step takes ``params`` as its first, non-donated argument
+    and the python wrappers pass it in: a closed-over array would be baked
+    into each executable as an HLO constant (one weight copy per compiled
+    step), and no step could be compiled ahead of time from shapes alone.
 
     ``slots`` is the tree width T = 1 + decoding_length the serving loop pads
     every draft to.  ``prefill_len`` fixes the prompt pad length so prefill
@@ -153,7 +158,7 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
 
     if cfg.kv_layout == "paged":
         @functools.partial(jax.jit, donate_argnums=())
-        def _prefill(tokens, lens, block_tables, lane_params):
+        def _prefill(params, tokens, lens, block_tables, lane_params):
             cache = tx.init_paged_cache(cfg, tokens.shape[0], n_blocks)
             cache["block_tables"] = jnp.asarray(block_tables, jnp.int32)
             cache, last_logits = tx.prefill_paged(cfg, params, tokens, lens,
@@ -161,15 +166,17 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
             return cache, _choose_last(tokens, lens, last_logits,
                                        lane_params)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _prefill_into_slot(cache, slot, tokens, lens, lane_params):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def _prefill_into_slot(params, cache, slot, tokens, lens,
+                               lane_params):
             cache, last_logits = tx.prefill_into_slot_paged(
                 cfg, params, cache, slot, tokens, lens)
             return cache, _choose_last(tokens, lens, last_logits,
                                        lane_params)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _tree_step(cache, cache_lens, tokens, pos, mask, lane_params):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def _tree_step(params, cache, cache_lens, tokens, pos, mask,
+                       lane_params):
             cache, logits = tx.tree_step_paged(cfg, params, cache,
                                                cache_lens, tokens, pos, mask)
             if logits_transform is not None:
@@ -182,9 +189,9 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
             return tx.commit_paged_cache(cfg, cache, cache_lens, gather_idx,
                                          n_accept)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _fused_step(cache, cache_lens, tokens, pos, mask, parent, n_live,
-                        lane_params):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def _fused_step(params, cache, cache_lens, tokens, pos, mask, parent,
+                        n_live, lane_params):
             cache, logits = tx.tree_step_paged(cfg, params, cache,
                                                cache_lens, tokens, pos, mask)
             if logits_transform is not None:
@@ -205,8 +212,9 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
         # token shape, so the compile count equals the number of distinct
         # buckets actually used — never the number of requests (lane and
         # offset are traced scalars).
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _prefill_suffix(cache, slot, tokens, offset, slen, lane_params):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def _prefill_suffix(params, cache, slot, tokens, offset, slen,
+                            lane_params):
             cache, last_logits = tx.prefill_from_offset_paged(
                 cfg, params, cache, slot, tokens, offset, slen)
             lg = last_logits[:, None, :]
@@ -251,7 +259,7 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
             _len_buf[0] = n
             if lane_params is None:
                 lane_params = _default_lane_params(1)
-            return _prefill_suffix(cache, slot, padded,
+            return _prefill_suffix(params, cache, slot, padded,
                                    _off_buf, _len_buf, lane_params)
 
         def copy_block(cache, src, dst):
@@ -263,25 +271,26 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
         def prefill(tokens, lens, block_tables, lane_params=None):
             if lane_params is None:
                 lane_params = _default_lane_params(tokens.shape[0])
-            return _prefill(tokens, lens, block_tables, lane_params)
+            return _prefill(params, tokens, lens, block_tables, lane_params)
 
         def prefill_into_slot(cache, slot, tokens, lens, lane_params=None):
             if lane_params is None:
                 lane_params = _default_lane_params(tokens.shape[0])
-            return _prefill_into_slot(cache, slot, tokens, lens, lane_params)
+            return _prefill_into_slot(params, cache, slot, tokens, lens,
+                                      lane_params)
 
         def tree_step(cache, cache_lens, tokens, pos, mask,
                       lane_params=None):
             if lane_params is None:
                 lane_params = _default_lane_params(tokens.shape[0])
-            return _tree_step(cache, cache_lens, tokens, pos, mask,
+            return _tree_step(params, cache, cache_lens, tokens, pos, mask,
                               lane_params)
 
         def fused_step(cache, cache_lens, tokens, pos, mask, parent, n_live,
                        lane_params=None):
             if lane_params is None:
                 lane_params = _default_lane_params(tokens.shape[0])
-            return _fused_step(cache, cache_lens, tokens, pos, mask,
+            return _fused_step(params, cache, cache_lens, tokens, pos, mask,
                                parent, n_live, lane_params)
 
         return StepFns(prefill=_expose(prefill, _prefill),
@@ -303,19 +312,19 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                        sampling=sampling)
 
     @functools.partial(jax.jit, donate_argnums=())
-    def _prefill(tokens, lens, lane_params):
+    def _prefill(params, tokens, lens, lane_params):
         cache = tx.init_cache(cfg, tokens.shape[0])
         cache, last_logits = tx.prefill(cfg, params, tokens, lens, cache)
         return cache, _choose_last(tokens, lens, last_logits, lane_params)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _prefill_into_slot(cache, slot, tokens, lens, lane_params):
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def _prefill_into_slot(params, cache, slot, tokens, lens, lane_params):
         cache, last_logits = tx.prefill_into_slot(cfg, params, cache, slot,
                                                   tokens, lens)
         return cache, _choose_last(tokens, lens, last_logits, lane_params)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _tree_step(cache, cache_lens, tokens, pos, mask, lane_params):
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def _tree_step(params, cache, cache_lens, tokens, pos, mask, lane_params):
         cache, logits = tx.tree_step(cfg, params, cache, cache_lens,
                                      tokens, pos, mask)
         if logits_transform is not None:
@@ -327,9 +336,9 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
     def _commit(cache, cache_lens, gather_idx, n_accept):
         return tx.commit_cache(cache, cache_lens, gather_idx, n_accept)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _fused_step(cache, cache_lens, tokens, pos, mask, parent, n_live,
-                    lane_params):
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def _fused_step(params, cache, cache_lens, tokens, pos, mask, parent,
+                    n_live, lane_params):
         cache, logits = tx.tree_step(cfg, params, cache, cache_lens,
                                      tokens, pos, mask)
         if logits_transform is not None:
@@ -350,23 +359,25 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
     def prefill(tokens, lens, lane_params=None):
         if lane_params is None:
             lane_params = _default_lane_params(tokens.shape[0])
-        return _prefill(tokens, lens, lane_params)
+        return _prefill(params, tokens, lens, lane_params)
 
     def prefill_into_slot(cache, slot, tokens, lens, lane_params=None):
         if lane_params is None:
             lane_params = _default_lane_params(tokens.shape[0])
-        return _prefill_into_slot(cache, slot, tokens, lens, lane_params)
+        return _prefill_into_slot(params, cache, slot, tokens, lens,
+                                  lane_params)
 
     def tree_step(cache, cache_lens, tokens, pos, mask, lane_params=None):
         if lane_params is None:
             lane_params = _default_lane_params(tokens.shape[0])
-        return _tree_step(cache, cache_lens, tokens, pos, mask, lane_params)
+        return _tree_step(params, cache, cache_lens, tokens, pos, mask,
+                          lane_params)
 
     def fused_step(cache, cache_lens, tokens, pos, mask, parent, n_live,
                    lane_params=None):
         if lane_params is None:
             lane_params = _default_lane_params(tokens.shape[0])
-        return _fused_step(cache, cache_lens, tokens, pos, mask,
+        return _fused_step(params, cache, cache_lens, tokens, pos, mask,
                            parent, n_live, lane_params)
 
     return StepFns(prefill=_expose(prefill, _prefill),

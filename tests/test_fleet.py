@@ -24,6 +24,7 @@ from repro.core.strategies import LookaheadConfig
 from repro.core.trie import TrieForest, TrieTree
 from repro.fleet import (DraftStateError, EngineReplica, FleetRouter,
                          GossipCoordinator)
+from repro.fleet.replica import ReplicaError
 from repro.fleet.persist import (collect_draft_state, install_draft_state,
                                  load_draft_state, save_draft_state)
 from repro.models.transformer import init_params
@@ -478,3 +479,15 @@ def test_subprocess_replica_matches_inproc():
 
 test_subprocess_replica_matches_inproc = pytest.mark.slow(
     test_subprocess_replica_matches_inproc)
+
+
+def test_subprocess_replica_refused_on_tpu_host(monkeypatch):
+    """A chip belongs to one process: on a TPU backend the parent already
+    holds it, so a spawned replica is refused before anything spawns."""
+    import repro.fleet.replica as replica_mod
+    monkeypatch.setattr(replica_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ReplicaError, match="one in-process replica per chip"):
+        EngineReplica(build_tiny, replica_id="s", mode="subprocess")
+    with pytest.raises(ValueError, match="device="):
+        EngineReplica(build_tiny, mode="subprocess",
+                      device=jax.devices()[0])

@@ -91,6 +91,47 @@ def test_mixed_params_lossless_per_request(layout, backend):
             (layout, backend, q)
 
 
+@pytest.mark.parametrize("layout,backend", [("dense", "dense"),
+                                            ("paged", "pallas")])
+def test_full_width_reference_replays_served_admission(layout, backend):
+    """reference_decode(lanes=n, like=stats) decodes through the very
+    programs an n-lane engine compiled — first-cohort prefill, slot
+    prefill, or suffix prefill after the same prefix-cache hit — with no
+    new executable, and agrees with the served tokens and with the width-1
+    reference."""
+    paged = layout == "paged"
+    eng = build_engine(
+        dataclasses.replace(_ECFG, kv_layout=layout, backend=backend,
+                            block_size=8 if paged else 64,
+                            prefix_cache=paged),
+        _CFG, _PARAMS)
+    head = _prompts(1, lo=12, hi=13, seed=40)[0]
+    tails = _prompts(4, lo=3, hi=8, seed=41)
+    # the short first request retires first, so the last one (same head)
+    # is admitted mid-flight onto its cached prefix
+    prompts = [head + tails[0], tails[1], tails[2], head + tails[3]]
+    plist = _mix(4, seed=42)
+    plist[0] = dataclasses.replace(plist[0], max_new_tokens=3)
+    handles = [eng.submit(Request(prompt=p, params=q))
+               for p, q in zip(prompts, plist)]
+    eng.run()
+    stats = [h.result().stats for h in handles]
+    admitted = {s.prefill for s in stats}
+    assert admitted == ({"prefill", "prefill_into_slot", "prefill_suffix"}
+                        if paged else {"prefill", "prefill_into_slot"})
+    members = ("prefill", "prefill_into_slot", "fused_step") + (
+        ("prefill_suffix",) if paged else ())
+    compiled = [getattr(eng.fns, m)._cache_size() for m in members]
+    full = [reference_decode(eng.fns, p, params=q, lanes=_ECFG.lanes, like=s)
+            for p, q, s in zip(prompts, plist, stats)]
+    assert [getattr(eng.fns, m)._cache_size() for m in members] == compiled
+    for h, f, p, q in zip(handles, full, prompts, plist):
+        assert h.result().tokens == f == reference_decode(eng.fns, p,
+                                                          params=q)
+    with pytest.raises(ValueError, match="lanes="):
+        reference_decode(eng.fns, prompts[0], params=plist[0], like=stats[0])
+
+
 def test_seed_controls_sampled_stream():
     """Distinct seeds give distinct streams; equal seeds equal streams
     (sampling is a pure function of (seed, position, logits))."""

@@ -36,6 +36,7 @@ def test_flash_decode_equals_dense_8dev():
     _run_subprocess("""
         import jax, numpy as np, dataclasses
         import jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.models import transformer as tx
         from repro.distributed.sharding import sharding_ctx
         cfg = tx.TransformerConfig(n_layers=2, d_model=64, n_heads=8,
@@ -59,7 +60,8 @@ def test_flash_decode_equals_dense_8dev():
         mask = jnp.asarray(np.stack([m] * B))
         c1, l1 = tx.tree_step(cfg, params, dict(cache), lens, toks, pos, mask)
         cfg2 = dataclasses.replace(cfg, decode_backend="flash_decode")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         with sharding_ctx(mesh):
             fn = jax.jit(lambda c, le, t, p, mm:
                          tx.tree_step(cfg2, params, c, le, t, p, mm))
@@ -76,6 +78,7 @@ def test_moe_ep_equals_ref_8dev():
     _run_subprocess("""
         import jax, numpy as np
         import jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.models import moe as M
         rng = np.random.RandomState(0)
         N, D, E, F, k = 96, 16, 8, 24, 2
@@ -85,7 +88,8 @@ def test_moe_ep_equals_ref_8dev():
         wu = jnp.asarray(rng.randn(E, D, F).astype(np.float32) * 0.2)
         wd = jnp.asarray(rng.randn(E, F, D).astype(np.float32) * 0.2)
         ref = M.moe_ref(x, wr, wg, wu, wd, k)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ep = M.moe_ep(x, wr, wg, wu, wd, k, capacity_factor=8.0, mesh=mesh)
         assert np.allclose(np.asarray(ref), np.asarray(ep), atol=1e-4)
         # gradients flow through the EP path (all_to_all transposes)
@@ -117,6 +121,45 @@ def test_elastic_checkpoint_reshard_8dev():
             np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(x))
             shard_shape = out["w"].sharding.shard_shape(out["w"].shape)
             assert shard_shape == (4, 4), shard_shape
+        print("OK")
+    """)
+
+
+def test_replicas_pinned_to_devices_8dev():
+    """In-process replicas pinned to devices 2 and 5 keep their weights,
+    caches and steps there, and serve the single engine's tokens."""
+    _run_subprocess("""
+        import jax, numpy as np
+        from repro.fleet import EngineReplica, FleetRouter
+        from repro.models.transformer import TransformerConfig, init_params
+        from repro.serving.api import EngineConfig, build_engine
+        cfg = TransformerConfig(n_layers=1, d_model=32, n_heads=4,
+                                n_kv_heads=2, d_ff=64, vocab_size=53,
+                                max_seq_len=96)
+        ecfg = EngineConfig(lanes=2, prefill_len=16, decoding_length=4,
+                            branch_length=3)
+        params = init_params(cfg, jax.random.key(3))
+        devs = [jax.devices()[2], jax.devices()[5]]
+        reps = [EngineReplica(lambda d=d: build_engine(
+                    ecfg, cfg, jax.device_put(params, d)),
+                    replica_id=f"r{i}", device=d)
+                for i, d in enumerate(devs)]
+        router = FleetRouter(reps, policy="round_robin")
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(1, 53, size=5 + i).tolist() for i in range(6)]
+        for p in prompts:
+            router.submit(p)
+        router.drain()
+        single = build_engine(ecfg, cfg, params)
+        ref = [single.submit(p) for p in prompts]
+        single.run()
+        assert [r["tokens"] for r in router.results()] == \
+            [h.result().tokens for h in ref]
+        for rep, d in zip(reps, devs):
+            cache = rep.engine.scheduler.cache
+            assert {x for leaf in jax.tree.leaves(cache)
+                    for x in leaf.devices()} == {d}
+            assert rep.engine.scheduler.stats.finished == 3
         print("OK")
     """)
 
