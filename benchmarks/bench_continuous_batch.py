@@ -32,6 +32,7 @@ from benchmarks.common import (VOCAB, bench_model, emit,
 from repro.core import (DraftPolicy, LookaheadConfig, LookaheadEngine,
                         Request, SamplingParams, reference_decode)
 from repro.serving.scheduler import ContinuousScheduler
+from repro.serving.spans import step_table
 
 PREFILL_LEN = 64
 LANES = 4
@@ -49,17 +50,19 @@ def _mixed_params(budgets):
 
 
 def _continuous(fns, la, prompts, specs, lanes, draft_policy=None,
-                overlap=False, record_breakdown=False, prefix_cache=False
+                overlap=False, spans=False, prefix_cache=False
                 ) -> Tuple[list, float, object, int]:
     """One scheduler generation; ``specs`` are per-request budgets (ints,
     legacy submit) or SamplingParams (request-centric submit).  Returns the
-    scheduler itself so callers can read stats AND per-step breakdowns."""
+    scheduler itself so callers can read stats AND (``spans``) its span
+    log's per-step records."""
     sched = ContinuousScheduler(fns, la, lanes=lanes,
                                 prefill_len=PREFILL_LEN,
                                 draft_policy=draft_policy,
                                 overlap_drafts=overlap,
-                                record_breakdown=record_breakdown,
                                 prefix_cache=prefix_cache)
+    if spans:
+        sched.spans.enable()
     t0 = time.perf_counter()
     for p, s in zip(prompts, specs):
         if isinstance(s, SamplingParams):
@@ -245,7 +248,8 @@ def run_breakdown(n_queries: int = 16, max_new: int = 48, lanes: int = LANES,
     Asserts (a) outputs bit-identical between the two modes, (b) exactly
     ONE host sync per decode step in both (the packed accept array is the
     only device->host transfer on the hot path).  Emits CSV lines and
-    optionally a JSON document (the BENCH trajectory seed).
+    optionally a JSON document (the BENCH trajectory seed) whose per-step
+    records are the scheduler's serve.step spans and their phases.
     """
     import json
 
@@ -275,7 +279,7 @@ def run_breakdown(n_queries: int = 16, max_new: int = 48, lanes: int = LANES,
                         overlap=overlap)                    # compile warmup
             out, wall, sched, _ = _continuous(fns_b, la, prompts, budgets,
                                               lanes, overlap=overlap,
-                                              record_breakdown=True)
+                                              spans=True)
             st = sched.stats
             assert st.decode_syncs == st.decode_steps, (layout, mode)
             br = st.breakdown()
@@ -294,7 +298,9 @@ def run_breakdown(n_queries: int = 16, max_new: int = 48, lanes: int = LANES,
                 "hidden_host_ms": round(br["hidden_host_ms"], 4),
                 "visible_host_ms": round(visible[mode], 4),
                 "tokens_per_s": round(tok / wall, 2),
-                "steps": sched.step_breakdown[:200],
+                "steps": [{k: round(v, 4) for k, v in r.items()
+                           if k != "start_ns"}
+                          for r in step_table(sched.spans.records())[:200]],
             }
             doc["cells"][f"{layout}/{mode}"] = cell
             step_ms = (br["host_draft_ms"] + br["device_step_ms"]

@@ -212,17 +212,9 @@ class GenStats:
     # tagged.
     source_drafted: Dict[str, int] = field(default_factory=dict)
     source_accepted: Dict[str, int] = field(default_factory=dict)
-    # per-step latency breakdown (scheduler runs only): each decode step's
-    # measured wall-clock split accrues onto EVERY request riding that step
-    # — exact per-step sums, not batch-level means, so co-resident requests
-    # of different lengths report their own step mix.  host_syncs counts
-    # device->host pulls attributed to it (fused path: exactly one per
-    # decode step it participated in).
-    host_draft_ms: float = 0.0     # draft build + tree packing per step
-    device_step_ms: float = 0.0    # dispatch -> packed result on host
-    accept_commit_ms: float = 0.0  # accept bookkeeping + retire + tables
-    hidden_host_ms: float = 0.0    # deferred retirement drained behind the
-    #                                step's device flight window (overlap)
+    # device->host pulls attributed to the request (fused path: exactly
+    # one per decode step it rode, plus the first-token pull); the time of
+    # each step it rode is in the scheduler's serve.step spans (riders)
     host_syncs: int = 0
     # prompt tokens served from the prefix cache (prefill compute skipped)
     cached_prompt_tokens: int = 0
@@ -286,9 +278,12 @@ class RequestState:
     inserted_upto: int = 0    # output tokens already streamed into the trie
     lane: int = -1            # scheduler slot currently occupied (-1 = none)
     submit_t: float = 0.0
-    admit_t: float = 0.0
+    admit_t: float = 0.0      # admission picked it (before the block claim)
     first_token_t: float = 0.0
     finish_t: float = 0.0
+    # the request's open serve.queue / serve.admit / serve.prefill span
+    # while the scheduler's span log is on (serving/spans.py)
+    span: Any = None
 
     @property
     def _limit(self) -> int:

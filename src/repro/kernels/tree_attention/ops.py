@@ -51,9 +51,11 @@ def ungroup_out(out: jax.Array, T: int, dh: int) -> jax.Array:
 
 def kv_view(cache: jax.Array) -> jax.Array:
     """(..., K, dh) cache → (..., K*dh_p), dh padded to 128 lanes; a free
-    reshape when dh is already a lane multiple."""
-    c = _pad_to(cache, cache.ndim - 1, 128)
-    return c.reshape(c.shape[:-2] + (-1,))
+    reshape when dh is already a lane multiple (in row-major order: a
+    tiled device layout may relayout it, under the ``kv_pool`` scope)."""
+    with jax.named_scope("kv_pool"):
+        c = _pad_to(cache, cache.ndim - 1, 128)
+        return c.reshape(c.shape[:-2] + (-1,))
 
 
 def mask_tiles(mask: jax.Array, bs: int) -> jax.Array:

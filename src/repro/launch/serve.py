@@ -42,6 +42,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import attention as attn_backends
 from repro.models import transformer as tx
 from repro.serving.api import EngineConfig, build_engine
+from repro.serving.spans import STEP_PHASES, request_table, step_table
 from repro.training.checkpoint import CheckpointManager
 from repro.training.data import PROFILES, SyntheticCorpus
 
@@ -64,6 +65,29 @@ def _request_params(args, i: int) -> SamplingParams:
         return SamplingParams(max_new_tokens=max_new)
     return SamplingParams(max_new_tokens=max_new, sample=args.sample,
                           temperature=args.temperature, seed=0)
+
+
+def _print_spans(log) -> None:
+    """Mean ms of each decode-step phase (draft + settle + pack is the
+    breakdown's draft), request phases, and the programs compiled."""
+    records = log.records()
+    rows = [r for r in step_table(records) if r["riders"]]
+    n = max(len(rows), 1)
+    print("step phases: " + "   ".join(
+        f"{p} {sum(r[f'{p}_ms'] for r in rows) / n:.2f} ms"
+        for p in STEP_PHASES))
+    reqs = request_table(records).values()
+
+    def p95(key):
+        return _pct([r[key] for r in reqs if key in r], 95)
+    admit = [r["admit_ms"] for r in reqs if "admit_ms" in r]
+    print(f"request phases: queue p95 {p95('queue_ms'):.1f} ms   admit mean "
+          f"{sum(admit) / max(len(admit), 1):.2f} ms   prefill p95 "
+          f"{p95('prefill_ms'):.1f} ms   engine ttft p95 "
+          f"{p95('engine_ttft_ms'):.1f} ms")
+    compiled = sorted({s.attr for s in records if s.name == "serve.compile"})
+    print(f"compiled while serving: {len(compiled)} programs "
+          f"({', '.join(compiled)}); {log.dropped} spans over the log's cap")
 
 
 def main() -> None:
@@ -342,6 +366,8 @@ def main() -> None:
     streamed = [0]          # tokens observed through handle callbacks
     handles = []
     cancelled = []
+    # the loop's host spans feed the phase split printed below
+    engine.scheduler.spans.enable()
 
     t0 = time.time()
     nxt = 0
@@ -400,6 +426,7 @@ def main() -> None:
           f"accept {br['accept_commit_ms']:.2f} ms   "
           f"hidden {br['hidden_host_ms']:.2f} ms   "
           f"{br['syncs_per_step']:.1f} sync/step")
+    _print_spans(engine.scheduler.spans)
     # per-tenant deployments report latency through namespace_summary():
     # pooled percentiles over all requests let a hot tenant's volume dilute
     # a cold tenant's p99 (the SLO the shares exist to protect), so the

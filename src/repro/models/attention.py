@@ -60,8 +60,9 @@ def scatter_kv(k_cache: jax.Array, v_cache: jax.Array, cache_lens: jax.Array,
     B, T = k.shape[:2]
     bidx = jnp.arange(B)[:, None]
     sidx = cache_lens[:, None] + jnp.arange(T)[None, :]
-    k_cache = k_cache.at[bidx, sidx].set(k.astype(k_cache.dtype))
-    v_cache = v_cache.at[bidx, sidx].set(v.astype(v_cache.dtype))
+    with jax.named_scope("kv_pool"):
+        k_cache = k_cache.at[bidx, sidx].set(k.astype(k_cache.dtype))
+        v_cache = v_cache.at[bidx, sidx].set(v.astype(v_cache.dtype))
     return k_cache, v_cache
 
 
@@ -74,11 +75,12 @@ def scatter_kv_paged(k_cache: jax.Array, v_cache: jax.Array,
     NULL-block garbage of idle lanes ever collides."""
     nb, bs, K, dh = k_cache.shape
     flat = slot_rows.reshape(-1)
-    kf = k_cache.reshape(nb * bs, K, dh)
-    vf = v_cache.reshape(nb * bs, K, dh)
-    kf = kf.at[flat].set(k.reshape(-1, K, dh).astype(k_cache.dtype))
-    vf = vf.at[flat].set(v.reshape(-1, K, dh).astype(v_cache.dtype))
-    return kf.reshape(k_cache.shape), vf.reshape(v_cache.shape)
+    with jax.named_scope("kv_pool"):
+        kf = k_cache.reshape(nb * bs, K, dh)
+        vf = v_cache.reshape(nb * bs, K, dh)
+        kf = kf.at[flat].set(k.reshape(-1, K, dh).astype(k_cache.dtype))
+        vf = vf.at[flat].set(v.reshape(-1, K, dh).astype(v_cache.dtype))
+        return kf.reshape(k_cache.shape), vf.reshape(v_cache.shape)
 
 
 def build_full_tree_mask(cache_lens: jax.Array, tree_mask: jax.Array,
@@ -175,10 +177,11 @@ class AttentionBackend:
                                                 k, v)
             nb, bs_, K, dh = k_cache.shape
             flat = all_rows.reshape(-1)
-            kg = jnp.take(k_cache.reshape(nb * bs_, K, dh), flat, axis=0
-                          ).reshape(B, S_virtual, K, dh)
-            vg = jnp.take(v_cache.reshape(nb * bs_, K, dh), flat, axis=0
-                          ).reshape(B, S_virtual, K, dh)
+            with jax.named_scope("kv_pool"):
+                kg = jnp.take(k_cache.reshape(nb * bs_, K, dh), flat, axis=0
+                              ).reshape(B, S_virtual, K, dh)
+                vg = jnp.take(v_cache.reshape(nb * bs_, K, dh), flat, axis=0
+                              ).reshape(B, S_virtual, K, dh)
             out = gqa_attention(q, kg, vg, full_mask,
                                 softmax_in_f32=cfg.attn_score_f32)
             return out, k_cache, v_cache

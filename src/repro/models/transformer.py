@@ -15,6 +15,7 @@ All tensors carry logical-axis sharding hints (repro.distributed.sharding).
 from __future__ import annotations
 
 import dataclasses
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -238,23 +239,25 @@ def _layer_self(cfg: TransformerConfig, lp: Params, h: jax.Array,
                 want_kv: bool = True):
     """Self-attention layer over the full sequence (train / prefill).
     Returns new hidden states and the (k, v) tensors for cache filling."""
-    hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, hn)
-    cos, sin = rope_angles(positions, cfg.dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    q = constrain(q, "batch", "seq", "heads", None)
-    k = constrain(k, "batch", "seq", "kv_heads", None)
-    v = constrain(v, "batch", "seq", "kv_heads", None)
-    backend = attn_backends.get_backend(cfg.prefill_backend)
-    attn = backend.prefill_attention(cfg, q, k, v, positions, len_mask)
-    B, T, H, dh = attn.shape
-    h = h + attn.reshape(B, T, H * dh) @ lp["wo"]
-    h = constrain(h, "batch", "residual_seq", None)
-    h = h + _ffn(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
-    # residual_seq: () by default; train cells map it to ("model",) so the
-    # remat-saved residual stream is sequence-sharded (Megatron-SP style)
-    h = constrain(h, "batch", "residual_seq", None)
+    with jax.named_scope("attn"):
+        hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, lp, hn)
+        cos, sin = rope_angles(positions, cfg.dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        q = constrain(q, "batch", "seq", "heads", None)
+        k = constrain(k, "batch", "seq", "kv_heads", None)
+        v = constrain(v, "batch", "seq", "kv_heads", None)
+        backend = attn_backends.get_backend(cfg.prefill_backend)
+        attn = backend.prefill_attention(cfg, q, k, v, positions, len_mask)
+        B, T, H, dh = attn.shape
+        h = h + attn.reshape(B, T, H * dh) @ lp["wo"]
+        h = constrain(h, "batch", "residual_seq", None)
+    with jax.named_scope("mlp"):
+        h = h + _ffn(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
+        # residual_seq: () by default; train cells map it to ("model",) so
+        # the remat-saved residual stream is sequence-sharded (Megatron-SP)
+        h = constrain(h, "batch", "residual_seq", None)
     return h, ((k, v) if want_kv else None)
 
 
@@ -269,15 +272,17 @@ def _layer_tree(cfg: TransformerConfig, lp: Params, h: jax.Array,
     at cache_len + slot, then attends the slots against the cache.
     """
     B, T, _ = h.shape
-    hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, hn)
-    cos, sin = rope_angles(positions, cfg.dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn, k_cache, v_cache = attend(q, k, v, k_cache, v_cache)
-    H, dh = cfg.n_heads, cfg.dh
-    h = h + attn.reshape(B, T, H * dh) @ lp["wo"]
-    h = h + _ffn(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
+    with jax.named_scope("attn"):
+        hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, lp, hn)
+        cos, sin = rope_angles(positions, cfg.dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        attn, k_cache, v_cache = attend(q, k, v, k_cache, v_cache)
+        H, dh = cfg.n_heads, cfg.dh
+        h = h + attn.reshape(B, T, H * dh) @ lp["wo"]
+    with jax.named_scope("mlp"):
+        h = h + _ffn(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps))
     return h, (k_cache, v_cache)
 
 
@@ -290,12 +295,13 @@ def _embed(cfg: TransformerConfig, params: Params, tokens: jax.Array
 
 def _unembed(cfg: TransformerConfig, params: Params, h: jax.Array
              ) -> jax.Array:
-    h = rms_norm(h, params["ln_f"], cfg.norm_eps)
-    w = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = h @ w.astype(h.dtype)
-    if logits.ndim == 3:
-        logits = constrain(logits, "batch", "seq", "vocab_act")
-    return logits
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["ln_f"], cfg.norm_eps)
+        w = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
+        logits = h @ w.astype(h.dtype)
+        if logits.ndim == 3:
+            logits = constrain(logits, "batch", "seq", "vocab_act")
+        return logits
 
 
 def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
@@ -310,6 +316,10 @@ def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
     xs (tree-decode cache update): in unrolled mode write y back into the
     stacked xs buffer with .at[i].set — XLA aliases this in place, so the
     unrolled path does NOT hold n_layers live cache copies.
+
+    Per-layer xs (the stacked KV pool, in the decode steps) are sliced out
+    and written back under the ``kv_pool`` scope; ``layer_fn``'s own ops
+    carry the scopes it opens (``attn``, ``mlp``).
     """
     # §Perf (train cells): cast the stacked weights to the activation dtype
     # BEFORE the layer loop — XLA hoists the loop-invariant fsdp all-gather
@@ -326,20 +336,24 @@ def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
 
     if cfg.remat:
         body = jax.checkpoint(body)
+    pool = jax.named_scope("kv_pool") if extra_xs else nullcontext()
     if cfg.scan_layers:
-        h, ys = jax.lax.scan(body, h, (lps, extra_xs))
+        with pool:
+            h, ys = jax.lax.scan(body, h, (lps, extra_xs))
         return h, ys
     # unrolled
     buf = extra_xs
     ys_buf = None
     for i in range(cfg.n_layers):
         lp_i = jax.tree.map(lambda a: a[i], lps)
-        xs_i = jax.tree.map(lambda a: a[i], buf)
+        with pool:
+            xs_i = jax.tree.map(lambda a: a[i], buf)
         h, y = body(h, (lp_i, xs_i))
         if y is None:
             continue
         if alias_ys_to_xs:
-            buf = jax.tree.map(lambda acc, yy: acc.at[i].set(yy), buf, y)
+            with pool:
+                buf = jax.tree.map(lambda acc, yy: acc.at[i].set(yy), buf, y)
         else:
             if ys_buf is None:
                 ys_buf = jax.tree.map(
@@ -536,12 +550,13 @@ def _scatter_paged_rows(cache: Dict[str, jax.Array], rows: jax.Array,
     k, v = cache["k"], cache["v"]
     L, nb, bs, K, dh = k.shape
     flat = rows.reshape(-1)
-    kf = k.reshape(L, nb * bs, K, dh)
-    vf = v.reshape(L, nb * bs, K, dh)
-    kf = kf.at[:, flat].set(k_new.reshape(L, -1, K, dh).astype(k.dtype))
-    vf = vf.at[:, flat].set(v_new.reshape(L, -1, K, dh).astype(v.dtype))
-    return {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
-            "block_tables": cache["block_tables"]}
+    with jax.named_scope("kv_pool"):
+        kf = k.reshape(L, nb * bs, K, dh)
+        vf = v.reshape(L, nb * bs, K, dh)
+        kf = kf.at[:, flat].set(k_new.reshape(L, -1, K, dh).astype(k.dtype))
+        vf = vf.at[:, flat].set(v_new.reshape(L, -1, K, dh).astype(v.dtype))
+        return {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
+                "block_tables": cache["block_tables"]}
 
 
 def prefill_paged(cfg: TransformerConfig, params: Params, tokens: jax.Array,
@@ -647,10 +662,12 @@ def copy_paged_block(cache: Dict[str, jax.Array], src: jax.Array,
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     out = dict(cache)
-    for name in ("k", "v"):
-        buf = cache[name]                                 # (L, nb, bs, K, dh)
-        blk = jax.lax.dynamic_slice_in_dim(buf, src, 1, axis=1)
-        out[name] = jax.lax.dynamic_update_slice_in_dim(buf, blk, dst, axis=1)
+    with jax.named_scope("kv_pool"):
+        for name in ("k", "v"):
+            buf = cache[name]                             # (L, nb, bs, K, dh)
+            blk = jax.lax.dynamic_slice_in_dim(buf, src, 1, axis=1)
+            out[name] = jax.lax.dynamic_update_slice_in_dim(buf, blk, dst,
+                                                            axis=1)
     return out
 
 
@@ -692,14 +709,16 @@ def commit_paged_cache(cfg: TransformerConfig, cache: Dict[str, jax.Array],
     dst = cache_lens[:, None] + jnp.arange(T)[None, :]
     src_rows = paged_row_index(bt, src, cfg.kv_block_size).reshape(-1)
     dst_rows = paged_row_index(bt, dst, cfg.kv_block_size).reshape(-1)
-    kf = k.reshape(L, nb * bs, K, dh)
-    vf = v.reshape(L, nb * bs, K, dh)
-    kg = kf[:, src_rows]                                    # (L, B*T, K, dh)
-    vg = vf[:, src_rows]
-    kf = kf.at[:, dst_rows].set(kg)
-    vf = vf.at[:, dst_rows].set(vg)
-    return {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
-            "block_tables": bt}, cache_lens + n_accept
+    with jax.named_scope("kv_pool"):
+        kf = k.reshape(L, nb * bs, K, dh)
+        vf = v.reshape(L, nb * bs, K, dh)
+        kg = kf[:, src_rows]                                # (L, B*T, K, dh)
+        vg = vf[:, src_rows]
+        kf = kf.at[:, dst_rows].set(kg)
+        vf = vf.at[:, dst_rows].set(vg)
+        new = {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
+               "block_tables": bt}
+    return new, cache_lens + n_accept
 
 
 def reset_blocks(cache: Dict[str, jax.Array], block_ids: jax.Array
@@ -775,10 +794,11 @@ def commit_cache(cache: Dict[str, jax.Array], cache_lens: jax.Array,
     bidx = jnp.arange(B)[:, None]
     src = cache_lens[:, None] + gather_idx                       # (B, T)
     dst = cache_lens[:, None] + jnp.arange(T)[None, :]
-    kg = k[:, bidx, src]                                         # (L,B,T,K,dh)
-    vg = v[:, bidx, src]
-    k = k.at[:, bidx, dst].set(kg)
-    v = v.at[:, bidx, dst].set(vg)
+    with jax.named_scope("kv_pool"):
+        kg = k[:, bidx, src]                                     # (L,B,T,K,dh)
+        vg = v[:, bidx, src]
+        k = k.at[:, bidx, dst].set(kg)
+        v = v.at[:, bidx, dst].set(vg)
     return {"k": k, "v": v}, cache_lens + n_accept
 
 

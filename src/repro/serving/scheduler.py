@@ -82,6 +82,7 @@ from repro.core.trie import TrieTree
 from repro.core.verify import verify_accept_batch
 from repro.serving.block_allocator import BlockAllocator, demand_blocks
 from repro.serving.prefix_cache import PrefixCache
+from repro.serving.spans import Phases, SpanLog
 
 if TYPE_CHECKING:   # avoid a load-time cycle: api.py imports the scheduler
     from repro.serving.api import RequestHandle
@@ -171,8 +172,11 @@ class SchedulerStats:
         self.finished = 0
         self.block_waits = 0     # admissions deferred for blocks, not lanes
         self.peak_blocks = 0     # max physical blocks allocated at once
-        # ---- per-step latency breakdown (totals over decode steps)
-        self.host_draft_ms = 0.0     # draft retrieval/merging + tree packing
+        # ---- per-step latency breakdown (totals over decode steps; the
+        # serve.step span's phases split them: SpanLog in serving/spans.py)
+        self.host_draft_ms = 0.0     # before dispatch: drafting (draft),
+        #                              blocking on deferred admission
+        #                              prefills (settle), stacking (pack)
         self.device_step_ms = 0.0    # dispatch -> packed result on the host
         self.accept_commit_ms = 0.0  # accept bookkeeping, retire, tables
         self.hidden_host_ms = 0.0    # host work run while a step was in
@@ -257,7 +261,6 @@ class ContinuousScheduler:
                  draft_policy: Optional[DraftPolicy] = None,
                  sources: Optional[Dict[str, DraftSource]] = None,
                  overlap_drafts: bool = False,
-                 record_breakdown: bool = False,
                  prefix_cache: bool = False,
                  prefix_cache_blocks: Optional[int] = None,
                  lane_shares: Optional[Dict[str, float]] = None,
@@ -271,8 +274,8 @@ class ContinuousScheduler:
                              "single-dispatch step the overlap window hides "
                              "host work behind)")
         self.overlap_drafts = bool(overlap_drafts)
-        self.record_breakdown = bool(record_breakdown)
-        self.step_breakdown: List[Dict[str, float]] = []
+        # host spans of the loop (serving/spans.py): off until enabled
+        self.spans = SpanLog()
         # overlap mode: requests retired at step k whose heavy bookkeeping
         # (trie elimination, block free + scrub, handle finalize) is deferred
         # into step k+1's in-flight window, and admissions whose
@@ -489,6 +492,19 @@ class ContinuousScheduler:
             self.stats.decode_syncs += 1
         return np.asarray(x)
 
+    @staticmethod
+    def _next_span(rs: RequestState, name: str,
+                   t_ns: Optional[int] = None) -> None:
+        """End the request's open span at ``t_ns`` (default: now) and start
+        ``name`` there (``name`` empty: start none).  A request queued while
+        the span log was off has no span to continue."""
+        if rs.span is not None:
+            log = rs.span.log
+            t = time.perf_counter_ns() if t_ns is None else t_ns
+            rs.span.close(t)
+            rs.span = (log.open(name, t, rid=rs.rid)
+                       if name and log.enabled else None)
+
     # ---------------------------------------------------------- draft sources
     @property
     def trie(self) -> TrieTree:
@@ -704,7 +720,10 @@ class ContinuousScheduler:
         if policy.adaptive and self.width > 1:
             rs.budget_ctl = AdaptiveBudget.from_policy(
                 policy, min(self.config.decoding_length, self.width - 1))
-        rs.submit_t = time.perf_counter()
+        t = time.perf_counter_ns()
+        rs.submit_t = t * 1e-9
+        if self.spans.enabled:
+            rs.span = self.spans.open("serve.queue", t, rid=rid)
         if self.sanitizer is not None:
             self.sanitizer.transition(rid, "queued")
         nsn = policy.namespace
@@ -754,6 +773,7 @@ class ContinuousScheduler:
                 if nsn is None:
                     break
                 rs = self.queues[nsn][0]
+                t_pick = time.perf_counter_ns()
                 if self.allocator is not None and \
                         not self._claim_blocks(rs, lane):
                     # not enough reservable blocks: ALL admission waits (the
@@ -764,7 +784,8 @@ class ContinuousScheduler:
                 self._take_queued(nsn)
                 in_use[nsn] = in_use.get(nsn, 0) + 1
                 rs.lane = lane
-                rs.admit_t = time.perf_counter()
+                rs.admit_t = t_pick * 1e-9
+                self._next_span(rs, "serve.admit", t_pick)
                 self._set_lane_params(lane, rs.params)
                 self._observe_prompt(rs)
                 self._sync_tables()
@@ -780,6 +801,7 @@ class ContinuousScheduler:
                                                     cow_dst)
                     suffix = np.asarray([rs.prompt[n_cached:]],
                                         dtype=np.int32)
+                    self._next_span(rs, "serve.prefill")
                     self.cache, chosen = fns.prefill_suffix(
                         self.cache, lane, suffix, n_cached,
                         lane_params=self._lane_params_one(rs.params))
@@ -791,6 +813,7 @@ class ContinuousScheduler:
                     toks[0, :len(rs.prompt)] = np.asarray(rs.prompt,
                                                           dtype=np.int32)
                     plen = np.asarray([len(rs.prompt)], dtype=np.int32)
+                    self._next_span(rs, "serve.prefill")
                     if fns.per_lane_params:
                         self.cache, chosen = fns.prefill_into_slot(
                             self.cache, lane, toks, plen,
@@ -823,21 +846,22 @@ class ContinuousScheduler:
             if nsn is None:
                 break
             rs = self.queues[nsn][0]
+            t_pick = time.perf_counter_ns()
             if self.allocator is not None and \
                     not self._claim_blocks(rs, len(cohort)):
                 break
             self._take_queued(nsn)
             in_use[nsn] = in_use.get(nsn, 0) + 1
+            rs.admit_t = t_pick * 1e-9
+            self._next_span(rs, "serve.admit", t_pick)
             cohort.append(rs)
         if not cohort:
             return []
         toks = np.full((self.lanes, self.prefill_len), fns.pad_id,
                        dtype=np.int32)
         lens = np.ones((self.lanes,), dtype=np.int32)   # dummy rows: 1 pad
-        now = time.perf_counter()
         for lane, rs in enumerate(cohort):
             rs.lane = lane
-            rs.admit_t = now
             rs.stats.prefill = "prefill"
             self._set_lane_params(lane, rs.params)
             self._observe_prompt(rs)
@@ -846,6 +870,9 @@ class ContinuousScheduler:
             lens[lane] = len(rs.prompt)
         lane_kw = ({"lane_params": self._lane_params_all()}
                    if fns.per_lane_params else {})
+        t = time.perf_counter_ns()
+        for rs in cohort:
+            self._next_span(rs, "serve.prefill", t)
         if self.allocator is not None:
             self.cache, chosen = fns.prefill(toks, lens, self.tables.copy(),
                                              **lane_kw)
@@ -874,7 +901,9 @@ class ContinuousScheduler:
         already finished at prefill (budget 1 / instant EOS) — its lane
         stays free for the next scheduler iteration."""
         rs.start(first_token)
-        rs.first_token_t = time.perf_counter()
+        t = time.perf_counter_ns()
+        rs.first_token_t = t * 1e-9
+        self._next_span(rs, "", t)
         rs.stats.host_syncs += 1        # the first-token pull
         self.stats.admitted += 1
         self._emit(rs, rs.output)
@@ -937,7 +966,10 @@ class ContinuousScheduler:
             self._drain_retired(finished)
             return finished
         fused = fns.fused_step is not None
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
+        # serve.step's phases tile the iteration (SpanLog, off by default)
+        ph = (Phases(self.spans, "serve.step", "serve.draft", t0)
+              if self.spans.enabled else None)
         # ---- host draft building.  In overlap mode any admission prefill
         # dispatched by _admit is still in flight here: draft retrieval /
         # merging for the established lanes runs behind that device work.
@@ -956,7 +988,11 @@ class ContinuousScheduler:
                 # and its block free already rides in _retired
                 continue
             chosen = self._pending_chosen[lane]
+            if ph:
+                ph.to("serve.settle")
             if self._settle(rs, int(self._pull(chosen)[0]), lane):
+                if ph:
+                    ph.to("serve.draft")
                 trees[lane] = self._build_tree(rs)
             elif rs.rid not in self.results:
                 finished.append(self._finish(rs))
@@ -966,11 +1002,15 @@ class ContinuousScheduler:
         self._pending_chosen.clear()
         active = [l for l in range(self.lanes) if self.states[l] is not None]
         if not active:
+            # every deferred admission finished at prefill: no device step
+            # (a serve.step without riders)
+            if ph:
+                ph.close(time.perf_counter_ns(), ())
             self._drain_retired(finished)
             return finished
-        # requests riding THIS step (captured before retirement clears
-        # lanes): each accrues the step's measured wall-clock split — exact
-        # per-step sums, not global means (satellite: telemetry skew)
+        if ph:
+            ph.to("serve.pack")
+        # requests riding THIS step (captured before retirement clears lanes)
         riders = [self.states[l] for l in active]
         for l in range(self.lanes):
             if trees[l] is None:
@@ -982,8 +1022,10 @@ class ContinuousScheduler:
         self._sync_tables()
         lane_kw = ({"lane_params": self._lane_params_all()}
                    if fns.per_lane_params else {})
-        t1 = time.perf_counter()
-        drained = 0.0
+        t1 = time.perf_counter_ns()
+        if ph:
+            ph.to("serve.dispatch", t1)
+        drained = 0
         new_lens = self.lens.copy()
         if fused:
             # ---- single-dispatch hot path: tree forward + token choice +
@@ -1002,12 +1044,21 @@ class ContinuousScheduler:
             if self._retired:
                 # overlap window: the step is in flight — run the previous
                 # step's deferred heavy retirement behind it
-                td = time.perf_counter()
+                td = time.perf_counter_ns()
+                if ph:
+                    ph.to("serve.retire", td)
                 self._drain_retired(finished)
-                drained = time.perf_counter() - td
-                self.stats.hidden_host_ms += drained * 1e3
+                te = time.perf_counter_ns()
+                drained = te - td
+                self.stats.hidden_host_ms += drained * 1e-6
+                if ph:
+                    ph.to("serve.pull", te)
+            elif ph:
+                ph.to("serve.pull")
             packed = self._pull(packed, decode=True)   # THE one sync point
-            t2 = time.perf_counter()
+            t2 = time.perf_counter_ns()
+            if ph:
+                ph.to("serve.accept", t2)
             accepted = [packed[l, 1:1 + packed[l, 0]]
                         for l in range(self.lanes)]
             kv_slots = [packed[l, 1 + W:1 + W + packed[l, 0]]
@@ -1029,8 +1080,12 @@ class ContinuousScheduler:
             else:
                 self.cache, chosen = fns.tree_step(self.cache, self.lens,
                                                    tok, pos, mask)
+            if ph:
+                ph.to("serve.pull")
             chosen = self._pull(chosen, decode=True)
-            t2 = time.perf_counter()
+            t2 = time.perf_counter_ns()
+            if ph:
+                ph.to("serve.accept", t2)
             accepted, kv_slots = verify_accept_batch(trees, chosen)
             gather = np.zeros((self.lanes, W), dtype=np.int32)
             n_acc = np.zeros((self.lanes,), dtype=np.int32)
@@ -1074,33 +1129,15 @@ class ContinuousScheduler:
                     self.lens[l] = 0
         if self.allocator is not None:
             self._extend_tables(active)
-        t3 = time.perf_counter()
-        hd = (t1 - t0) * 1e3
-        dv = (t2 - t1 - drained) * 1e3
-        ac = (t3 - t2) * 1e3
-        hh = drained * 1e3
-        self.stats.host_draft_ms += hd
-        self.stats.device_step_ms += dv
-        self.stats.accept_commit_ms += ac
-        # per-request breakdown: every rider of this step accrues the step's
-        # actual split (a short request co-resident with long ones reports
-        # only the steps it rode — not a whole-run mean — and the hidden
-        # host work drained behind its flight window is no longer dropped)
-        for rs in riders:
-            rst = rs.stats
-            rst.host_draft_ms += hd
-            rst.device_step_ms += dv
-            rst.accept_commit_ms += ac
-            rst.hidden_host_ms += hh
-        if self.record_breakdown:
-            self.step_breakdown.append({
-                "step": self.stats.decode_steps,
-                "active": len(active),
-                "host_draft_ms": hd,
-                "device_step_ms": dv,
-                "accept_commit_ms": ac,
-                "hidden_host_ms": hh,
-                "syncs": 1 if fused else 2})
+        t3 = time.perf_counter_ns()
+        # the same stamps bound the spans: draft + settle + pack spans sum
+        # to host_draft_ms, dispatch + pull to device_step_ms, accept to
+        # accept_commit_ms, retire to hidden_host_ms
+        self.stats.host_draft_ms += (t1 - t0) * 1e-6
+        self.stats.device_step_ms += (t2 - t1 - drained) * 1e-6
+        self.stats.accept_commit_ms += (t3 - t2) * 1e-6
+        if ph:
+            ph.close(t3, tuple(rs.rid for rs in riders))
         return finished
 
     def _extend_tables(self, active: List[int]) -> None:
@@ -1148,7 +1185,9 @@ class ContinuousScheduler:
                         # held nothing: queued requests retire directly
                         self.sanitizer.transition(rid, "retiring")
                         self.sanitizer.transition(rid, "drained")
-                    rs.finish_t = time.perf_counter()
+                    t = time.perf_counter_ns()
+                    rs.finish_t = t * 1e-9
+                    self._next_span(rs, "", t)
                     res = rs.result()
                     self.results[rid] = res
                     nst = self.stats.ns(rs.draft.namespace)
@@ -1184,7 +1223,9 @@ class ContinuousScheduler:
                     # the deferred drain (the in-flight prefill may still
                     # write into them — PR 8's use-after-free window)
                     self.sanitizer.transition(rid, "retiring")
-                rs.finish_t = time.perf_counter()
+                t = time.perf_counter_ns()
+                rs.finish_t = t * 1e-9
+                self._next_span(rs, "", t)
                 rs.lane = -1
                 if self.allocator is not None:
                     self.tables[lane, :] = 0

@@ -148,13 +148,24 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
             "seed": np.full((n,), defaults.seed, dtype=np.uint32),
         }
 
+    # Named scopes (metadata only; the compiled programs are the same
+    # without them): "head" for token choice here and the unembedding in
+    # models/transformer.py, "accept" for the device accept walk and
+    # commit; the model's layers carry "attn", "mlp" and "kv_pool".
     def _choose_last(tokens, lens, last_logits, lane_params):
-        lg = last_logits[:, None, :]
-        if logits_transform is not None:
-            last_tok = jnp.take_along_axis(tokens, (lens - 1)[:, None],
-                                           axis=1)
-            lg = logits_transform(lg, last_tok, (lens - 1)[:, None])
-        return _choose(lg, lens[:, None], lane_params)[:, 0]
+        with jax.named_scope("head"):
+            lg = last_logits[:, None, :]
+            if logits_transform is not None:
+                last_tok = jnp.take_along_axis(tokens, (lens - 1)[:, None],
+                                               axis=1)
+                lg = logits_transform(lg, last_tok, (lens - 1)[:, None])
+            return _choose(lg, lens[:, None], lane_params)[:, 0]
+
+    def _choose_tree(logits, tokens, pos, lane_params):
+        with jax.named_scope("head"):
+            if logits_transform is not None:
+                logits = logits_transform(logits, tokens, pos)
+            return _choose(logits, pos + 1, lane_params)
 
     if cfg.kv_layout == "paged":
         @functools.partial(jax.jit, donate_argnums=())
@@ -179,10 +190,7 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                        lane_params):
             cache, logits = tx.tree_step_paged(cfg, params, cache,
                                                cache_lens, tokens, pos, mask)
-            if logits_transform is not None:
-                logits = logits_transform(logits, tokens, pos)
-            chosen = _choose(logits, pos + 1, lane_params)
-            return cache, chosen
+            return cache, _choose_tree(logits, tokens, pos, lane_params)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _commit(cache, cache_lens, gather_idx, n_accept):
@@ -194,14 +202,13 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                         n_live, lane_params):
             cache, logits = tx.tree_step_paged(cfg, params, cache,
                                                cache_lens, tokens, pos, mask)
-            if logits_transform is not None:
-                logits = logits_transform(logits, tokens, pos)
-            chosen = _choose(logits, pos + 1, lane_params)
-            n_acc, acc_tok, kv_slots = tx.verify_accept_device(
-                tokens, parent, n_live, chosen)
-            cache, _ = tx.commit_paged_cache(cfg, cache, cache_lens,
-                                             kv_slots, n_acc)
-            return cache, tx.pack_step_result(n_acc, acc_tok, kv_slots)
+            chosen = _choose_tree(logits, tokens, pos, lane_params)
+            with jax.named_scope("accept"):
+                n_acc, acc_tok, kv_slots = tx.verify_accept_device(
+                    tokens, parent, n_live, chosen)
+                cache, _ = tx.commit_paged_cache(cfg, cache, cache_lens,
+                                                 kv_slots, n_acc)
+                return cache, tx.pack_step_result(n_acc, acc_tok, kv_slots)
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _reset_blocks(cache, block_ids):
@@ -217,14 +224,15 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                             lane_params):
             cache, last_logits = tx.prefill_from_offset_paged(
                 cfg, params, cache, slot, tokens, offset, slen)
-            lg = last_logits[:, None, :]
-            if logits_transform is not None:
-                last_tok = jnp.take_along_axis(tokens, (slen - 1)[:, None],
-                                               axis=1)
-                lg = logits_transform(lg, last_tok,
-                                      (offset + slen - 1)[:, None])
-            return cache, _choose(lg, (offset + slen)[:, None],
-                                  lane_params)[:, 0]
+            with jax.named_scope("head"):
+                lg = last_logits[:, None, :]
+                if logits_transform is not None:
+                    last_tok = jnp.take_along_axis(
+                        tokens, (slen - 1)[:, None], axis=1)
+                    lg = logits_transform(lg, last_tok,
+                                          (offset + slen - 1)[:, None])
+                return cache, _choose(lg, (offset + slen)[:, None],
+                                      lane_params)[:, 0]
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _copy_block(cache, src, dst):
@@ -238,29 +246,24 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
         suffix_buckets.append(_cap)
         suffix_buckets = tuple(suffix_buckets)
 
-        # preallocated staging buffers: jax copies numpy inputs at
-        # dispatch, so reusing host scratch across calls is safe and
-        # avoids three fresh allocations per suffix prefill
-        _pad_bufs = {b: np.full((1, b), pad_id, np.int32)
-                     for b in suffix_buckets}
-        _off_buf = np.zeros((1,), np.int32)
-        _len_buf = np.zeros((1,), np.int32)
-
         def prefill_suffix(cache, slot, tokens, offset, lane_params=None):
             """tokens (1, n): the UN-padded prompt suffix; offset: cached
-            prefix length.  Pads n up to the smallest suffix bucket."""
+            prefix length.  Pads n up to the smallest suffix bucket.
+
+            The inputs are fresh arrays on every call: a CPU device may
+            alias a numpy argument instead of copying it, and the dispatch
+            is asynchronous, so a buffer reused by the next admission could
+            change under a prefill that has not run yet."""
             tokens = np.asarray(tokens, np.int32)
             n = tokens.shape[1]
             bucket = next(b for b in suffix_buckets if b >= n)
-            padded = _pad_bufs[bucket]
+            padded = np.full((1, bucket), pad_id, np.int32)
             padded[0, :n] = tokens[0]
-            padded[0, n:] = pad_id
-            _off_buf[0] = offset
-            _len_buf[0] = n
             if lane_params is None:
                 lane_params = _default_lane_params(1)
             return _prefill_suffix(params, cache, slot, padded,
-                                   _off_buf, _len_buf, lane_params)
+                                   np.asarray([offset], np.int32),
+                                   np.asarray([n], np.int32), lane_params)
 
         def copy_block(cache, src, dst):
             return _copy_block(cache, np.int32(src), np.int32(dst))
@@ -327,10 +330,7 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
     def _tree_step(params, cache, cache_lens, tokens, pos, mask, lane_params):
         cache, logits = tx.tree_step(cfg, params, cache, cache_lens,
                                      tokens, pos, mask)
-        if logits_transform is not None:
-            logits = logits_transform(logits, tokens, pos)
-        chosen = _choose(logits, pos + 1, lane_params)
-        return cache, chosen
+        return cache, _choose_tree(logits, tokens, pos, lane_params)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _commit(cache, cache_lens, gather_idx, n_accept):
@@ -341,13 +341,12 @@ def make_session_fns(cfg: tx.TransformerConfig, params: tx.Params, *,
                     n_live, lane_params):
         cache, logits = tx.tree_step(cfg, params, cache, cache_lens,
                                      tokens, pos, mask)
-        if logits_transform is not None:
-            logits = logits_transform(logits, tokens, pos)
-        chosen = _choose(logits, pos + 1, lane_params)
-        n_acc, acc_tok, kv_slots = tx.verify_accept_device(
-            tokens, parent, n_live, chosen)
-        cache, _ = tx.commit_cache(cache, cache_lens, kv_slots, n_acc)
-        return cache, tx.pack_step_result(n_acc, acc_tok, kv_slots)
+        chosen = _choose_tree(logits, tokens, pos, lane_params)
+        with jax.named_scope("accept"):
+            n_acc, acc_tok, kv_slots = tx.verify_accept_device(
+                tokens, parent, n_live, chosen)
+            cache, _ = tx.commit_cache(cache, cache_lens, kv_slots, n_acc)
+            return cache, tx.pack_step_result(n_acc, acc_tok, kv_slots)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _reset_slot(cache, slot):

@@ -339,16 +339,17 @@ def test_cancel_of_pending_overlap_admission_defers_block_free(layout):
 
 
 def test_breakdown_accrues_per_rider_steps(fns):
-    """Satellite regression (telemetry skew): a request's per-phase ms are
-    the SUM of the measured splits of exactly the decode steps it rode —
-    not a whole-run mean apportioned to everyone.  A short request
-    co-resident with a long one must report only its own steps' time, and
-    the overlap mode's hidden host ms must show up per request too."""
+    """Telemetry skew regression: a request's step time is the sum over
+    exactly the decode steps it rode — each serve.step span names its
+    riders, so a short request co-resident with a long one rides only its
+    own steps, and the overlap mode's hidden host work (serve.retire) lands
+    in the steps that drained it, on their riders."""
     from repro.core.request import Request, SamplingParams
     from repro.core.draft_sources import DraftPolicy
+    from repro.serving.spans import step_table
     prompts = _prompts(2, seed=62)
-    sched = ContinuousScheduler(fns, _la(), lanes=2, prefill_len=PREFILL,
-                                record_breakdown=True)
+    sched = ContinuousScheduler(fns, _la(), lanes=2, prefill_len=PREFILL)
+    sched.spans.enable()
     hs = sched.submit_request(Request(prompt=prompts[0], params=SamplingParams(
         max_new_tokens=4, draft=DraftPolicy(namespace="a"))))
     hl = sched.submit_request(Request(prompt=prompts[1], params=SamplingParams(
@@ -357,28 +358,38 @@ def test_breakdown_accrues_per_rider_steps(fns):
     short, long_ = hs.result(), hl.result()
     k = short.stats.steps - 1          # decode steps (start() counts one)
     n = long_.stats.steps - 1
-    assert 0 < k < n == len(sched.step_breakdown)
-    for field in ("host_draft_ms", "device_step_ms", "accept_commit_ms"):
-        assert getattr(short.stats, field) == pytest.approx(
-            sum(e[field] for e in sched.step_breakdown[:k]), rel=1e-9), field
-        assert getattr(long_.stats, field) == pytest.approx(
-            sum(e[field] for e in sched.step_breakdown), rel=1e-9), field
+    steps = [s for s in sched.spans.records() if s.name == "serve.step"]
+    assert 0 < k < n == len(steps) == sched.stats.decode_steps
+    assert [s for s in steps if short.rid in s.attr] == steps[:k]
+    assert all(long_.rid in s.attr for s in steps)
     # the long request rode more wall time than the short one
-    assert long_.stats.device_step_ms > short.stats.device_step_ms
+    assert sum(s.ms for s in steps) > sum(s.ms for s in steps[:k])
+    # the phases split the scheduler's totals
+    rows = step_table(sched.spans.records())
+    st = sched.stats
+    assert sum(r["dispatch_ms"] + r["pull_ms"] for r in rows) == \
+        pytest.approx(st.device_step_ms, rel=1e-6)
+    assert sum(r["accept_ms"] for r in rows) == pytest.approx(
+        st.accept_commit_ms, rel=1e-6)
     # per-namespace lane-step accounting matches the ride counts
     assert sched.stats.ns("a").lane_steps == k
     assert sched.stats.ns("b").lane_steps == n
 
-    # overlap mode: hidden host ms (bookkeeping drained inside the flight
-    # window) accrues on the riders of the draining steps — it was dropped
-    # entirely by the old global-mean stamping
+    # overlap mode: bookkeeping drained inside the flight window is its own
+    # phase of the draining step, which the long request rode
     sched2 = ContinuousScheduler(fns, _la(), lanes=2, prefill_len=PREFILL,
-                                 overlap_drafts=True, record_breakdown=True)
-    h2s = sched2.submit_request(Request(
+                                 overlap_drafts=True)
+    sched2.spans.enable()
+    sched2.submit_request(Request(
         prompt=prompts[0], params=SamplingParams(max_new_tokens=4)))
     h2l = sched2.submit_request(Request(
         prompt=prompts[1], params=SamplingParams(max_new_tokens=28)))
     sched2.run()
-    assert h2l.result().stats.hidden_host_ms > 0.0
-    assert h2l.result().stats.hidden_host_ms == pytest.approx(
-        sum(e["hidden_host_ms"] for e in sched2.step_breakdown), rel=1e-9)
+    recs = sched2.spans.records()
+    rode = {s.id for s in recs
+            if s.name == "serve.step" and h2l.result().rid in s.attr}
+    retire = [s for s in recs if s.name == "serve.retire"]
+    assert retire and {s.parent for s in retire} <= rode
+    assert sched2.stats.hidden_host_ms > 0.0
+    assert sum(s.ms for s in retire) == pytest.approx(
+        sched2.stats.hidden_host_ms, rel=1e-6)

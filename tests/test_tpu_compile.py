@@ -82,6 +82,94 @@ def test_flash_prefill_compiles_for_v5e(one_chip, S):
              ((1, S, K, DH), BF16))
 
 
+SCOPES = ("attn", "kv_pool", "mlp", "head", "accept")
+
+
+def _canonical_hlo(text: str) -> str:
+    """Compiled HLO without metadata, stack-frame tables and the numeric
+    suffixes that make instruction names unique (renumbered by first use:
+    passes may number intermediate instructions differently)."""
+    lines, skip = [], False
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skip = True
+        elif skip and not line.strip():
+            skip = False
+        elif not skip:
+            lines.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    seen = {}
+
+    def renumber(m):
+        return seen.setdefault(m.group(0), f"{m.group(1)}#{len(seen)}")
+    return re.sub(r"(?<![\w.])([A-Za-z_][\w\-]*)\.(\d+)\b", renumber,
+                  "\n".join(lines))
+
+
+def _compile_step_and_suffix(one_chip):
+    """The paged Pallas fused step and a suffix prefill at qwen2-1.5b's
+    attention widths (one layer), compiled for the described chip."""
+    from repro.models import transformer as tx
+    from repro.serving.session import make_session_fns
+    cfg = tx.TransformerConfig(n_layers=1, d_model=H * DH, n_heads=H,
+                               n_kv_heads=K, d_ff=256, vocab_size=512,
+                               qkv_bias=True, max_seq_len=256,
+                               dtype="bfloat16", param_dtype="bfloat16")
+    lanes, bs, nb = 2, 64, 9
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: tx.init_params(cfg, jax.random.key(0))))
+    fns = make_session_fns(cfg, params, slots=T, prefill_len=64,
+                           backend="pallas", kv_layout="paged",
+                           block_size=bs, n_blocks=nb, sampling="greedy")
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def lp(n):
+        return {"greedy": sd((n,), jnp.bool_), "temp": sd((n,), jnp.float32),
+                "seed": sd((n,), jnp.uint32)}
+    i32 = jnp.int32
+    cache = {"k": sd((1, nb, bs, K, DH), BF16),
+             "v": sd((1, nb, bs, K, DH), BF16),
+             "block_tables": sd((lanes, 256 // bs), i32)}
+    step = fns.fused_step._jitted.lower(
+        params, cache, sd((lanes,), i32), sd((lanes, T), i32),
+        sd((lanes, T), i32), sd((lanes, T, T), jnp.bool_),
+        sd((lanes, T), i32), sd((lanes,), i32), lp(lanes)).compile()
+    suffix = fns.prefill_suffix._jitted.lower(
+        params, cache, sd((), i32), sd((1, 64), i32), sd((1,), i32),
+        sd((1,), i32), lp(1)).compile()
+    return [c.as_text() for c in (step, suffix)]
+
+
+def test_named_scopes_are_metadata_only(one_chip, monkeypatch):
+    """The device programs carry the scopes a trace reduction reads
+    (attn, kv_pool, mlp, head, accept), and compile to the same program
+    without them: the scopes live in metadata alone."""
+    import contextlib
+    import repro.kernels.flash_prefill.ops as fp_ops
+    import repro.kernels.tree_attention.paged as ta_paged
+    for mod in (fp_ops, ta_paged):      # the Mosaic kernels, not interpret
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    # the kernels' own jits cache their traces and lowerings (with the
+    # source locations of whichever caller came first): start both
+    # compiles from empty caches
+    jax.clear_caches()
+    scoped = _compile_step_and_suffix(one_chip)
+    names = set(re.findall(r'op_name="[^"]*/(' + "|".join(SCOPES) + r')/',
+                           scoped[0]))
+    assert names == set(SCOPES), names
+    assert "%_paged_tree_attention" in scoped[0]      # the kernel's name
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    plain = _compile_step_and_suffix(one_chip)
+    assert not re.search(r'op_name="[^"]*/kv_pool/', plain[0])
+    for a, b in zip(scoped, plain):
+        assert _canonical_hlo(a) == _canonical_hlo(b)
+
+
 def test_fused_step_takes_weights_as_arguments():
     """Lowering the dense fused step leaves no weight-shaped constant: a
     closed-over weight would be baked in as ``stablehlo.constant`` (one
