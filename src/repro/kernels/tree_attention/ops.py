@@ -49,13 +49,24 @@ def ungroup_out(out: jax.Array, T: int, dh: int) -> jax.Array:
     return out.transpose(0, 3, 1, 2, 4).reshape(B, T, -1, dh)
 
 
+def lane_dh(dh: int) -> int:
+    """Head size as the kernels read it: dh padded up to 128 lanes."""
+    return -(-dh // 128) * 128
+
+
 def kv_view(cache: jax.Array) -> jax.Array:
-    """(..., K, dh) cache → (..., K*dh_p), dh padded to 128 lanes; a free
-    reshape when dh is already a lane multiple (in row-major order: a
-    tiled device layout may relayout it, under the ``kv_pool`` scope)."""
+    """(..., K, dh) cache → (..., K*dh_p), dh padded to ``lane_dh(dh)``; a
+    free reshape when dh is already a lane multiple (in row-major order: a
+    tiled device layout may relayout it, under the ``kv_pool`` scope).
+    The paged pool is stored in this layout, so only new rows pass here."""
     with jax.named_scope("kv_pool"):
         c = _pad_to(cache, cache.ndim - 1, 128)
         return c.reshape(c.shape[:-2] + (-1,))
+
+
+def kv_unview(rows: jax.Array, K: int, dh: int) -> jax.Array:
+    """Inverse of ``kv_view``: (..., K*dh_p) → (..., K, dh)."""
+    return rows.reshape(rows.shape[:-1] + (K, -1))[..., :dh]
 
 
 def mask_tiles(mask: jax.Array, bs: int) -> jax.Array:
@@ -115,4 +126,5 @@ def tree_attention_reference(q, k_cache, v_cache, mask):
     return out.reshape(B, T, H, dh)
 
 
-__all__ = ["tree_attention", "tree_attention_reference", "default_interpret"]
+__all__ = ["tree_attention", "tree_attention_reference", "default_interpret",
+           "lane_dh", "kv_view", "kv_unview"]

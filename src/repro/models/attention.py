@@ -13,6 +13,11 @@ the two serving phases:
     ``attend(q, k_new, v_new, k_cache, v_cache) -> (out, k_cache, v_cache)``
     that scatters the T draft-slot KV rows at ``cache_len + slot`` and
     attends the slots against the whole cache.
+  * ``make_paged_tree_attend(cfg, block_tables, cache_lens, tree_mask)`` —
+    the same over the paged pool: ``attend(q, k_new, v_new, k_pool, v_pool,
+    layer) -> (out, k_pool, v_pool)`` takes the whole (L, n_blocks,
+    block_size, K*dh_p) pool that the layer scan carries, writes only this
+    layer's new rows and reads this layer's blocks through the tables.
 
 Per-phase selection lives on ``TransformerConfig``: ``prefill_backend`` and
 ``decode_backend`` name a registered backend (the registry replaces the old
@@ -48,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.kernels.tree_attention.ops import kv_unview, kv_view
 from repro.models.layers import (causal_prefill_mask, gqa_attention,
                                  gqa_attention_chunked)
 
@@ -66,21 +72,36 @@ def scatter_kv(k_cache: jax.Array, v_cache: jax.Array, cache_lens: jax.Array,
     return k_cache, v_cache
 
 
-def scatter_kv_paged(k_cache: jax.Array, v_cache: jax.Array,
+def pool_rows(pool: jax.Array, rows: jax.Array, layer=None) -> jax.Array:
+    """Indices into the paged pool's flat (L*n_blocks*block_size, K*dh_p)
+    view (a bitcast of (L, n_blocks, block_size, K*dh_p)) of the physical
+    ``rows`` of ``layer``, or of every layer (layer-major) when None.
+    Gathers and scatters of whole rows on that view keep the pool's own
+    layout; indexing a layer axis beside the rows would have XLA relay the
+    pool out to make the layer axis part of each row."""
+    if layer is None:
+        layer = jnp.arange(pool.shape[0])[:, None]
+    return (layer * (pool.shape[1] * pool.shape[2])
+            + rows.reshape(-1)).reshape(-1)
+
+
+def scatter_kv_paged(k_pool: jax.Array, v_pool: jax.Array, layer,
                      slot_rows: jax.Array, k: jax.Array, v: jax.Array
                      ) -> Tuple[jax.Array, jax.Array]:
-    """Paged twin of ``scatter_kv``: write the (B, T) draft-slot KV rows at
-    precomputed physical rows of the (n_blocks, block_size, K, dh) pool.
-    Rows are distinct across lanes (block ownership is exclusive); only
-    NULL-block garbage of idle lanes ever collides."""
-    nb, bs, K, dh = k_cache.shape
-    flat = slot_rows.reshape(-1)
+    """Paged twin of ``scatter_kv``: write the (B, T) draft-slot KV rows of
+    ``layer`` at precomputed physical rows of the (L, n_blocks, block_size,
+    K*dh_p) pool.  Only those rows are written: the pool carried through
+    the layer scan is updated in place.  Rows are distinct across lanes
+    (block ownership is exclusive); only NULL-block garbage of idle lanes
+    ever collides."""
+    KD = k_pool.shape[-1]
+    idx = pool_rows(k_pool, slot_rows, layer)
     with jax.named_scope("kv_pool"):
-        kf = k_cache.reshape(nb * bs, K, dh)
-        vf = v_cache.reshape(nb * bs, K, dh)
-        kf = kf.at[flat].set(k.reshape(-1, K, dh).astype(k_cache.dtype))
-        vf = vf.at[flat].set(v.reshape(-1, K, dh).astype(v_cache.dtype))
-        return kf.reshape(k_cache.shape), vf.reshape(v_cache.shape)
+        kr = kv_view(k).reshape(-1, KD).astype(k_pool.dtype)
+        vr = kv_view(v).reshape(-1, KD).astype(v_pool.dtype)
+        kf = k_pool.reshape(-1, KD).at[idx].set(kr)
+        vf = v_pool.reshape(-1, KD).at[idx].set(vr)
+        return kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
 
 
 def build_full_tree_mask(cache_lens: jax.Array, tree_mask: jax.Array,
@@ -161,30 +182,32 @@ class AttentionBackend:
     def make_paged_tree_attend(self, cfg, block_tables: jax.Array,
                                cache_lens: jax.Array, tree_mask: jax.Array,
                                slot_valid=None) -> Callable:
-        """Tree-decode closure over the paged cache — per-layer caches are
-        the (n_blocks, block_size, K, dh) block pool.  Reference semantics:
-        gather each lane's blocks back into a contiguous (B, S_virtual)
-        window via ``jnp.take`` and reuse the dense math (parity oracle for
-        the streaming kernel; positions beyond a lane's coverage resolve to
-        NULL-block garbage and are masked)."""
+        """Tree-decode closure over the paged pool
+        ``attend(q, k, v, k_pool, v_pool, layer) -> (out, k_pool, v_pool)``
+        — the whole (L, n_blocks, block_size, K*dh_p) pool is carried
+        through the layer scan and ``layer`` picks this layer's rows.
+        Reference semantics: gather each lane's blocks back into a
+        contiguous (B, S_virtual) window and reuse the dense math (parity
+        oracle for the streaming kernel; positions beyond a lane's coverage
+        resolve to NULL-block garbage and are masked)."""
         full_mask, slot_rows, all_rows, S_virtual = self._paged_geometry(
             cfg, block_tables, cache_lens, tree_mask, slot_valid)
         B = tree_mask.shape[0]
+        K, dh = cfg.n_kv_heads, cfg.dh
 
-        def attend(q, k, v, k_cache, v_cache):
+        def attend(q, k, v, k_pool, v_pool, layer):
             q = constrain(q, "batch", None, "heads", None)
-            k_cache, v_cache = scatter_kv_paged(k_cache, v_cache, slot_rows,
-                                                k, v)
-            nb, bs_, K, dh = k_cache.shape
-            flat = all_rows.reshape(-1)
+            k_pool, v_pool = scatter_kv_paged(k_pool, v_pool, layer,
+                                              slot_rows, k, v)
+            KD = k_pool.shape[-1]
+            idx = pool_rows(k_pool, all_rows, layer)
             with jax.named_scope("kv_pool"):
-                kg = jnp.take(k_cache.reshape(nb * bs_, K, dh), flat, axis=0
-                              ).reshape(B, S_virtual, K, dh)
-                vg = jnp.take(v_cache.reshape(nb * bs_, K, dh), flat, axis=0
-                              ).reshape(B, S_virtual, K, dh)
+                kg, vg = (kv_unview(p.reshape(-1, KD)[idx]
+                                    .reshape(B, S_virtual, KD), K, dh)
+                          for p in (k_pool, v_pool))
             out = gqa_attention(q, kg, vg, full_mask,
                                 softmax_in_f32=cfg.attn_score_f32)
-            return out, k_cache, v_cache
+            return out, k_pool, v_pool
 
         return attend
 
@@ -220,19 +243,20 @@ class PallasBackend(AttentionBackend):
     def make_paged_tree_attend(self, cfg, block_tables, cache_lens,
                                tree_mask, slot_valid=None):
         """Streaming paged decode: the kernel walks each lane's logical
-        blocks and a scalar-prefetched block table steers the DMA to the
-        physical block — no contiguous per-lane cache is ever materialized
-        (the jnp.take of the dense path disappears into addressing)."""
+        blocks and the scalar-prefetched block table and layer index steer
+        the DMA to the physical block of the carried pool — no contiguous
+        per-lane or per-layer cache is ever materialized (the jnp.take of
+        the dense path disappears into addressing)."""
         from repro.kernels.tree_attention.paged import paged_tree_attention
         full_mask, slot_rows, _, _ = self._paged_geometry(
             cfg, block_tables, cache_lens, tree_mask, slot_valid)
 
-        def attend(q, k, v, k_cache, v_cache):
-            k_cache, v_cache = scatter_kv_paged(k_cache, v_cache, slot_rows,
-                                                k, v)
-            out = paged_tree_attention(q, k_cache, v_cache, block_tables,
-                                       full_mask)
-            return out, k_cache, v_cache
+        def attend(q, k, v, k_pool, v_pool, layer):
+            k_pool, v_pool = scatter_kv_paged(k_pool, v_pool, layer,
+                                              slot_rows, k, v)
+            out = paged_tree_attention(q, k_pool, v_pool, block_tables,
+                                       full_mask, layer)
+            return out, k_pool, v_pool
 
         return attend
 
@@ -268,5 +292,5 @@ register_backend(FlashDecodeBackend())
 
 __all__ = ["AttentionBackend", "PallasBackend", "register_backend",
            "get_backend", "available_backends", "scatter_kv",
-           "scatter_kv_paged", "build_full_tree_mask",
+           "pool_rows", "scatter_kv_paged", "build_full_tree_mask",
            "dense_prefill_attention"]

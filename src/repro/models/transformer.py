@@ -24,7 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import active_mesh, constrain
+from repro.kernels.tree_attention.ops import kv_view, lane_dh
 from repro.models import attention as attn_backends
+from repro.models.attention import pool_rows
 from repro.models import moe as moe_lib
 from repro.models.layers import (ACTS, apply_rope, rms_norm, rope_angles,
                                  swiglu)
@@ -262,14 +264,15 @@ def _layer_self(cfg: TransformerConfig, lp: Params, h: jax.Array,
 
 
 def _layer_tree(cfg: TransformerConfig, lp: Params, h: jax.Array,
-                positions: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                attend: Any
+                positions: jax.Array, attend: Any, *cache: jax.Array
                 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Tree-decode layer: T slots attend to cache + tree siblings.
 
-    k_cache/v_cache: (B, S_max, K, dh).  ``attend`` is the backend closure
-    built by ``AttentionBackend.make_tree_attend`` — it scatters the new KV
-    at cache_len + slot, then attends the slots against the cache.
+    ``attend`` is the backend closure built by ``make_tree_attend`` (cache
+    = this layer's (B, S_max, K, dh) K and V) or ``make_paged_tree_attend``
+    (cache = the whole carried K and V pool and the layer index) — it
+    scatters the new KV at cache_len + slot, then attends the slots against
+    the cache, and returns the updated K and V.
     """
     B, T, _ = h.shape
     with jax.named_scope("attn"):
@@ -278,7 +281,7 @@ def _layer_tree(cfg: TransformerConfig, lp: Params, h: jax.Array,
         cos, sin = rope_angles(positions, cfg.dh, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        attn, k_cache, v_cache = attend(q, k, v, k_cache, v_cache)
+        attn, k_cache, v_cache = attend(q, k, v, *cache)
         H, dh = cfg.n_heads, cfg.dh
         h = h + attn.reshape(B, T, H * dh) @ lp["wo"]
     with jax.named_scope("mlp"):
@@ -304,6 +307,17 @@ def _unembed(cfg: TransformerConfig, params: Params, h: jax.Array
         return logits
 
 
+def _layer_weights(cfg: TransformerConfig, params: Params) -> Params:
+    """The stacked layer weights in the activation dtype.
+
+    §Perf (train cells): cast BEFORE the layer loop — XLA hoists the
+    loop-invariant fsdp all-gather out of the scan, and gathering the f32
+    master copy moves (and holds) 2x the bytes of the bf16 compute copy."""
+    return jax.tree.map(lambda a: a.astype(cfg.adtype)
+                        if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                        params["layers"])
+
+
 def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
                  layer_fn, extra_xs: Tuple = (), extra_args: Tuple = (),
                  alias_ys_to_xs: bool = False) -> Tuple[jax.Array, Tuple]:
@@ -317,17 +331,12 @@ def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
     stacked xs buffer with .at[i].set — XLA aliases this in place, so the
     unrolled path does NOT hold n_layers live cache copies.
 
-    Per-layer xs (the stacked KV pool, in the decode steps) are sliced out
-    and written back under the ``kv_pool`` scope; ``layer_fn``'s own ops
-    carry the scopes it opens (``attn``, ``mlp``).
+    Per-layer xs (the stacked dense KV cache, in the dense decode step)
+    are sliced out and written back under the ``kv_pool`` scope;
+    ``layer_fn``'s own ops carry the scopes it opens (``attn``, ``mlp``).
+    The paged pool does not pass here (``_scan_layers_pooled``).
     """
-    # §Perf (train cells): cast the stacked weights to the activation dtype
-    # BEFORE the layer loop — XLA hoists the loop-invariant fsdp all-gather
-    # out of the scan, and gathering the f32 master copy moves (and holds)
-    # 2x the bytes of the bf16 compute copy.
-    lps = jax.tree.map(lambda a: a.astype(cfg.adtype)
-                       if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                       params["layers"])
+    lps = _layer_weights(cfg, params)
 
     def body(h, xs):
         lp, xtra = xs
@@ -362,6 +371,39 @@ def _scan_layers(cfg: TransformerConfig, params: Params, h: jax.Array,
             ys_buf = jax.tree.map(lambda acc, yy: acc.at[i].set(yy),
                                   ys_buf, y)
     return h, (buf if alias_ys_to_xs else ys_buf)
+
+
+def _scan_layers_pooled(cfg: TransformerConfig, params: Params, h: jax.Array,
+                        layer_fn, k_pool: jax.Array, v_pool: jax.Array
+                        ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Run layer_fn over the layers with the paged KV pool as scan carry.
+
+    layer_fn(cfg, lp, h, k_pool, v_pool, layer) -> (h, (k_pool, v_pool))
+
+    The scan runs over the layer index beside the stacked weights; each
+    layer writes its new rows at ``[layer, rows]`` of the carried pool and
+    the kernel reads the pool at ``layer``, so no layer's slice is cut out
+    or written back and the donated pool buffer is updated in place.  The
+    unrolled path (cfg.scan_layers=False) threads the same carry with a
+    constant index.
+    """
+    lps = _layer_weights(cfg, params)
+
+    def body(carry, xs):
+        h, pools = carry
+        lp, layer = xs
+        h, pools = layer_fn(cfg, lp, h, *pools, layer)
+        return (h, pools), None
+
+    carry = (h, (k_pool, v_pool))
+    if cfg.scan_layers:
+        carry, _ = jax.lax.scan(
+            body, carry, (lps, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    else:
+        for i in range(cfg.n_layers):
+            carry, _ = body(carry, (jax.tree.map(lambda a: a[i], lps),
+                                    jnp.int32(i)))
+    return carry
 
 
 def train_logits(cfg: TransformerConfig, params: Params, tokens: jax.Array,
@@ -417,9 +459,10 @@ def init_cache(cfg: TransformerConfig, batch: int,
 
 def cache_logical_axes(cfg: TransformerConfig) -> Dict[str, Tuple]:
     if cfg.kv_layout == "paged":
-        # block pool is lane-agnostic: only the head axis is shardable
-        return {"k": (None, None, None, "kv_heads", None),
-                "v": (None, None, None, "kv_heads", None),
+        # block pool is lane-agnostic: only the head-flattened last axis
+        # (K heads of lane_dh(dh) columns) is shardable
+        return {"k": (None, None, None, "kv_heads"),
+                "v": (None, None, None, "kv_heads"),
                 "block_tables": (None, None)}
     if cfg.decode_backend == "flash_decode":
         return {"k": (None, None, "kv_seq", "kv_heads", None),
@@ -438,8 +481,14 @@ def init_paged_cache(cfg: TransformerConfig, lanes: int,
                      n_blocks: Optional[int] = None,
                      dtype: Optional[jnp.dtype] = None
                      ) -> Dict[str, jax.Array]:
-    """Block-pool KV cache: k/v (L, n_blocks, block_size, K, dh) plus the
+    """Block-pool KV cache: k/v (L, n_blocks, block_size, K*dh_p) plus the
     per-lane block tables (lanes, blocks_per_lane) int32.
+
+    The pool is stored in the paged kernel's layout: heads flattened into
+    the last axis, dh padded to a lane multiple (``lane_dh``; free at dh =
+    128, paid once in HBM elsewhere).  Every paged writer and reader works
+    on rows of the flat (L, n_blocks*block_size, K*dh_p) view, a bitcast,
+    so no program relays the pool out.
 
     ``n_blocks`` defaults to the dense-equivalent worst case (every lane can
     hold max_seq_len rows) plus the reserved NULL block 0; serving stacks
@@ -447,12 +496,12 @@ def init_paged_cache(cfg: TransformerConfig, lanes: int,
     layout's memory win.  Table entries start at 0 (the NULL block), where
     never-attended scatters land harmlessly.
     """
-    L, K, dh = cfg.n_layers, cfg.n_kv_heads, cfg.dh
+    L, KD = cfg.n_layers, cfg.n_kv_heads * lane_dh(cfg.dh)
     bs, bpl = cfg.kv_block_size, blocks_per_lane(cfg)
     nb = int(n_blocks) if n_blocks else 1 + lanes * bpl
     dt = dtype or cfg.adtype
-    return {"k": jnp.zeros((L, nb, bs, K, dh), dt),
-            "v": jnp.zeros((L, nb, bs, K, dh), dt),
+    return {"k": jnp.zeros((L, nb, bs, KD), dt),
+            "v": jnp.zeros((L, nb, bs, KD), dt),
             "block_tables": jnp.zeros((lanes, bpl), jnp.int32)}
 
 
@@ -548,13 +597,13 @@ def _scatter_paged_rows(cache: Dict[str, jax.Array], rows: jax.Array,
     of the paged pool.  Duplicate rows only ever arise on NULL-block
     garbage, where any write order is fine."""
     k, v = cache["k"], cache["v"]
-    L, nb, bs, K, dh = k.shape
-    flat = rows.reshape(-1)
+    KD = k.shape[-1]
+    idx = pool_rows(k, rows)
     with jax.named_scope("kv_pool"):
-        kf = k.reshape(L, nb * bs, K, dh)
-        vf = v.reshape(L, nb * bs, K, dh)
-        kf = kf.at[:, flat].set(k_new.reshape(L, -1, K, dh).astype(k.dtype))
-        vf = vf.at[:, flat].set(v_new.reshape(L, -1, K, dh).astype(v.dtype))
+        kf = k.reshape(-1, KD).at[idx].set(
+            kv_view(k_new).reshape(-1, KD).astype(k.dtype))
+        vf = v.reshape(-1, KD).at[idx].set(
+            kv_view(v_new).reshape(-1, KD).astype(v.dtype))
         return {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
                 "block_tables": cache["block_tables"]}
 
@@ -640,14 +689,12 @@ def prefill_from_offset_paged(cfg: TransformerConfig, params: Params,
 
     h = _embed(cfg, params, tokens)
 
-    def layer(cfg_, lp, h_, k_c, v_c):
-        return _layer_tree(cfg_, lp, h_, positions, k_c, v_c, attend)
+    def layer(cfg_, lp, h_, *pool):
+        return _layer_tree(cfg_, lp, h_, positions, attend, *pool)
 
-    h, kv = _scan_layers(cfg, params, h, layer,
-                         extra_xs=(cache["k"], cache["v"]), extra_args=(),
-                         alias_ys_to_xs=True)
-    new_cache = {"k": kv[0], "v": kv[1],
-                 "block_tables": cache["block_tables"]}
+    h, (k, v) = _scan_layers_pooled(cfg, params, h, layer, cache["k"],
+                                    cache["v"])
+    new_cache = {"k": k, "v": v, "block_tables": cache["block_tables"]}
     h_last = jnp.take_along_axis(
         h, (lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return new_cache, _unembed(cfg, params, h_last)
@@ -664,7 +711,7 @@ def copy_paged_block(cache: Dict[str, jax.Array], src: jax.Array,
     out = dict(cache)
     with jax.named_scope("kv_pool"):
         for name in ("k", "v"):
-            buf = cache[name]                             # (L, nb, bs, K, dh)
+            buf = cache[name]                             # (L, nb, bs, KD)
             blk = jax.lax.dynamic_slice_in_dim(buf, src, 1, axis=1)
             out[name] = jax.lax.dynamic_update_slice_in_dim(buf, blk, dst,
                                                             axis=1)
@@ -686,13 +733,12 @@ def tree_step_paged(cfg: TransformerConfig, params: Params,
 
     h = _embed(cfg, params, tokens)
 
-    def layer(cfg_, lp, h_, k_c, v_c):
-        return _layer_tree(cfg_, lp, h_, positions, k_c, v_c, attend)
+    def layer(cfg_, lp, h_, *pool):
+        return _layer_tree(cfg_, lp, h_, positions, attend, *pool)
 
-    h, kv = _scan_layers(cfg, params, h, layer,
-                         extra_xs=(cache["k"], cache["v"]), extra_args=(),
-                         alias_ys_to_xs=True)
-    new_cache = {"k": kv[0], "v": kv[1], "block_tables": bt}
+    h, (k, v) = _scan_layers_pooled(cfg, params, h, layer, cache["k"],
+                                    cache["v"])
+    new_cache = {"k": k, "v": v, "block_tables": bt}
     return new_cache, _unembed(cfg, params, h)
 
 
@@ -701,21 +747,22 @@ def commit_paged_cache(cfg: TransformerConfig, cache: Dict[str, jax.Array],
                        n_accept: jax.Array
                        ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Paged twin of ``commit_cache``: logical src/dst positions resolve
-    through the block tables before the gather/scatter."""
+    through the block tables before the gather/scatter, on the flat view of
+    the (donated) pool — only the accepted rows move."""
     k, v, bt = cache["k"], cache["v"], cache["block_tables"]
-    L, nb, bs, K, dh = k.shape
+    KD = k.shape[-1]
     B, T = gather_idx.shape
     src = cache_lens[:, None] + gather_idx                         # (B, T)
     dst = cache_lens[:, None] + jnp.arange(T)[None, :]
-    src_rows = paged_row_index(bt, src, cfg.kv_block_size).reshape(-1)
-    dst_rows = paged_row_index(bt, dst, cfg.kv_block_size).reshape(-1)
+    src_rows = pool_rows(k, paged_row_index(bt, src, cfg.kv_block_size))
+    dst_rows = pool_rows(k, paged_row_index(bt, dst, cfg.kv_block_size))
     with jax.named_scope("kv_pool"):
-        kf = k.reshape(L, nb * bs, K, dh)
-        vf = v.reshape(L, nb * bs, K, dh)
-        kg = kf[:, src_rows]                                # (L, B*T, K, dh)
-        vg = vf[:, src_rows]
-        kf = kf.at[:, dst_rows].set(kg)
-        vf = vf.at[:, dst_rows].set(vg)
+        kf = k.reshape(-1, KD)
+        vf = v.reshape(-1, KD)
+        kg = kf[src_rows]                                   # (L*B*T, KD)
+        vg = vf[src_rows]
+        kf = kf.at[dst_rows].set(kg)
+        vf = vf.at[dst_rows].set(vg)
         new = {"k": kf.reshape(k.shape), "v": vf.reshape(v.shape),
                "block_tables": bt}
     return new, cache_lens + n_accept
@@ -771,7 +818,7 @@ def tree_step(cfg: TransformerConfig, params: Params,
     attend = backend.make_tree_attend(cfg, cache_lens, tree_mask, S_max)
 
     def layer(cfg_, lp, h_, k_c, v_c):
-        return _layer_tree(cfg_, lp, h_, positions, k_c, v_c, attend)
+        return _layer_tree(cfg_, lp, h_, positions, attend, k_c, v_c)
 
     h, kv = _scan_layers(cfg, params, h, layer,
                          extra_xs=(cache["k"], cache["v"]), extra_args=(),

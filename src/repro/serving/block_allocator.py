@@ -1,8 +1,8 @@
 """Host-side block allocator for the paged KV cache (vLLM-style).
 
-The device cache is a pool of fixed-size blocks ``(n_blocks, block_size, K,
-dh)`` per layer; each request owns an ordered list of physical block ids (its
-*block table*) mapping logical token positions to cache rows:
+The device cache is a pool of fixed-size blocks ``(n_blocks, block_size,
+K*dh_p)`` per layer; each request owns an ordered list of physical block ids
+(its *block table*) mapping logical token positions to cache rows:
 
     phys_row(p) = table[p // block_size] * block_size + p % block_size
 

@@ -412,7 +412,8 @@ def test_ledger_cache_ref_pairing():
 def test_ledger_poison_probe():
     import numpy as np
     ledger = ShadowLedger()
-    cache = {"k": np.zeros((2, 8, 4, 2, 4)), "v": np.zeros((2, 8, 4, 2, 4))}
+    # the paged pool's layout: (L, n_blocks, block_size, K*dh_p)
+    cache = {"k": np.zeros((2, 8, 4, 256)), "v": np.zeros((2, 8, 4, 256))}
     ledger.on_scrubbed([3])
     ledger.check_poison(cache)          # all-zero: clean
     cache["k"][0, 3, 1] = 1.0           # stray write into freed block

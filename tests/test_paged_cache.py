@@ -38,12 +38,19 @@ def test_init_paged_cache_shapes_and_axes():
                                   "kv_block_size": 16})
     assert tx.blocks_per_lane(cfg) == 10          # ceil(160 / 16)
     cache = tx.init_paged_cache(cfg, lanes=3, n_blocks=7)
-    assert cache["k"].shape == (2, 7, 16, 2, 8)
-    assert cache["v"].shape == (2, 7, 16, 2, 8)
+    # the kernel's layout: K=2 heads of dh=8 padded to 128 lanes, flattened
+    assert cache["k"].shape == (2, 7, 16, 2 * 128)
+    assert cache["v"].shape == (2, 7, 16, 2 * 128)
     assert cache["block_tables"].shape == (3, 10)
     assert cache["block_tables"].dtype == jnp.int32
     axes = tx.cache_logical_axes(cfg)
     assert set(axes) == {"k", "v", "block_tables"}
+    assert axes["k"] == axes["v"] == (None, None, None, "kv_heads")
+    # a lane-multiple head size is stored unpadded
+    wide = tx.TransformerConfig(**{**cfg.__dict__, "d_model": 256,
+                                   "n_heads": 2, "n_kv_heads": 1})
+    assert tx.init_paged_cache(wide, lanes=1, n_blocks=3)["k"].shape == \
+        (2, 3, 16, 128)
     # default pool = dense-equivalent worst case + NULL block
     assert tx.init_paged_cache(cfg, lanes=2)["k"].shape[1] == 1 + 2 * 10
 
@@ -60,19 +67,36 @@ def test_paged_row_index_maps_through_tables():
 
 
 # ------------------------------------------------------------ kernel parity
+def _to_pool(logical):
+    """(L, nb, bs, K, dh) logical KV → the pool's (L, nb, bs, K*dh_p)."""
+    from repro.kernels.tree_attention.ops import kv_view
+    return kv_view(jnp.asarray(logical))
+
+
+def _from_pool(pool, K, dh):
+    """Inverse of ``_to_pool`` (the pad columns must stay zero)."""
+    p = np.asarray(pool)
+    p = p.reshape(p.shape[:-1] + (K, -1))
+    assert not p[..., dh:].any()
+    return p[..., :dh]
+
+
 @pytest.mark.kernels
 @pytest.mark.parametrize("dh,bs", [(8, 16), (16, 8), (8, 32)])
 def test_paged_kernel_matches_gather_reference(dh, bs):
-    """paged_tree_attention == dense attention over the gathered cache."""
+    """paged_tree_attention == dense attention over the gathered cache,
+    for every layer of a pool whose layers hold different contents (the
+    kernel must read the layer it is given)."""
     from repro.kernels.tree_attention.paged import paged_tree_attention
     from repro.models.layers import gqa_attention
 
     rng = np.random.RandomState(0)
-    B, T, H, K, nb, bpl = 3, 5, 4, 2, 9, 4
+    B, T, H, K, nb, bpl, L = 3, 5, 4, 2, 9, 4, 3
     S_virtual = bpl * bs
     q = jnp.asarray(rng.randn(B, T, H, dh), jnp.float32)
-    k_cache = jnp.asarray(rng.randn(nb, bs, K, dh), jnp.float32)
-    v_cache = jnp.asarray(rng.randn(nb, bs, K, dh), jnp.float32)
+    k_log = rng.randn(L, nb, bs, K, dh).astype(np.float32)
+    v_log = rng.randn(L, nb, bs, K, dh).astype(np.float32)
+    k_pool, v_pool = _to_pool(k_log), _to_pool(v_log)
     # distinct physical blocks per lane; lane 2 mostly NULL
     bt = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 7], [8, 0, 0, 0]], jnp.int32)
     lens = jnp.asarray([bs + 3, 2 * bs + 1, 4], jnp.int32)
@@ -80,19 +104,22 @@ def test_paged_kernel_matches_gather_reference(dh, bs):
     for b in range(B):
         tree[b] = np.tril(rng.rand(T, T) < 0.7) | np.eye(T, dtype=bool)
     mask = build_full_tree_mask(lens, jnp.asarray(tree), S_virtual)
-
-    out = paged_tree_attention(q, k_cache, v_cache, bt, mask)
-
-    flat = k_cache.reshape(nb * bs, K, dh)
-    flatv = v_cache.reshape(nb * bs, K, dh)
     pos = jnp.broadcast_to(jnp.arange(S_virtual)[None], (B, S_virtual))
-    rows = tx.paged_row_index(bt, pos, bs)
-    kg = jnp.take(flat, rows.reshape(-1), axis=0).reshape(B, S_virtual, K, dh)
-    vg = jnp.take(flatv, rows.reshape(-1), axis=0).reshape(B, S_virtual, K,
-                                                           dh)
-    ref = gqa_attention(q, kg, vg, mask)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    rows = tx.paged_row_index(bt, pos, bs).reshape(-1)
+
+    def ref(layer):
+        kg, vg = (jnp.take(jnp.asarray(c[layer]).reshape(nb * bs, K, dh),
+                           rows, axis=0).reshape(B, S_virtual, K, dh)
+                  for c in (k_log, v_log))
+        return np.asarray(gqa_attention(q, kg, vg, mask))
+
+    refs = [ref(layer) for layer in range(L)]
+    for layer in range(L):
+        out = np.asarray(paged_tree_attention(q, k_pool, v_pool, bt, mask,
+                                              layer))
+        np.testing.assert_allclose(out, refs[layer], rtol=2e-5, atol=2e-5)
+        other = refs[(layer + 1) % L]
+        assert np.abs(out - other).max() > 1e-2     # layers really differ
 
 
 # --------------------------------------------------------- prefill/commit I3
@@ -120,7 +147,7 @@ def test_paged_prefill_matches_dense_rows():
                                    jnp.asarray(lens), cache)
     np.testing.assert_allclose(np.asarray(last), np.asarray(dense_last),
                                rtol=1e-5, atol=1e-5)
-    kf = np.asarray(cache["k"]).reshape(2, 9 * 16, 2, 8)
+    kf = _from_pool(cache["k"], 2, 8).reshape(2, 9 * 16, 2, 8)
     rows = np.asarray(tx.paged_row_index(
         jnp.asarray(bt), jnp.arange(PREFILL)[None].repeat(2, 0), 16))
     for b in range(2):
@@ -187,6 +214,65 @@ def test_paged_reset_scrubs_freed_blocks_only():
     assert not k[:, 2].any() and not k[:, 4].any()
     for blk in (1, 3, 5):
         assert k[:, blk].all()
+
+
+def test_paged_pool_ops_touch_the_rows_they_name():
+    """commit, COW block copy, reset and the sanitizer's poison probe act on
+    the same logical rows as on an unflattened (L, nb, bs, K, dh) pool:
+    a numpy oracle over the logical pool predicts every row."""
+    from repro.analysis.sanitizer import InvariantViolation, ShadowLedger
+    cfg, _ = _model()
+    pcfg = tx.TransformerConfig(**{**cfg.__dict__, "kv_layout": "paged",
+                                   "kv_block_size": 4})
+    L, nb, bs, K, dh = 2, 7, 4, 2, 8
+    rng = np.random.RandomState(3)
+    k_log = rng.randn(L, nb, bs, K, dh).astype(np.float32)
+    v_log = rng.randn(L, nb, bs, K, dh).astype(np.float32)
+    bt = np.asarray([[2, 5, 0, 0], [4, 1, 6, 0]], np.int32)
+    cache = {"k": _to_pool(k_log), "v": _to_pool(v_log),
+             "block_tables": jnp.asarray(bt)}
+
+    # commit: lane b's logical row lens+j takes row lens+gather[j]
+    lens = np.asarray([3, 6], np.int32)
+    gather = np.asarray([[0, 2, 3], [0, 1, 4]], np.int32)
+    n_acc = np.asarray([3, 2], np.int32)
+    out, new_lens = tx.commit_paged_cache(pcfg, cache, jnp.asarray(lens),
+                                          jnp.asarray(gather),
+                                          jnp.asarray(n_acc))
+    np.testing.assert_array_equal(np.asarray(new_lens), lens + n_acc)
+    for name, log in (("k", k_log), ("v", v_log)):
+        want = log.reshape(L, nb * bs, K, dh).copy()
+        src = [bt[b, p // bs] * bs + p % bs
+               for b in range(2) for p in lens[b] + gather[b]]
+        dst = [bt[b, p // bs] * bs + p % bs
+               for b in range(2) for p in lens[b] + np.arange(3)]
+        want[:, dst] = log.reshape(L, nb * bs, K, dh)[:, src]
+        np.testing.assert_array_equal(
+            _from_pool(out[name], K, dh).reshape(L, nb * bs, K, dh), want)
+
+    # copy-on-write fork of block 5 into block 3, every layer, K and V
+    out = tx.copy_paged_block(cache, np.int32(5), np.int32(3))
+    for name, log in (("k", k_log), ("v", v_log)):
+        want = log.copy()
+        want[:, 3] = log[:, 5]
+        np.testing.assert_array_equal(_from_pool(out[name], K, dh), want)
+
+    # scrub blocks 1 and 6 (0 pads the id list); the rest untouched
+    out = tx.reset_blocks(cache, np.asarray([1, 6, 0], np.int32))
+    for name, log in (("k", k_log), ("v", v_log)):
+        want = log.copy()
+        want[:, [0, 1, 6]] = 0
+        np.testing.assert_array_equal(_from_pool(out[name], K, dh), want)
+
+    # the poison probe reads whole blocks: clean after the scrub, and a
+    # single stray row of a scrubbed block is caught
+    ledger = ShadowLedger()
+    ledger.on_scrubbed([1, 6])
+    ledger.check_poison(out)
+    k_bad = np.asarray(out["k"]).copy()
+    k_bad[1, 6, bs - 1, K * 128 - 128] = 1.0     # layer 1, last row, head 1
+    with pytest.raises(InvariantViolation, match="use-after-free write"):
+        ledger.check_poison({**out, "k": k_bad})
 
 
 def test_paged_finish_admit_interleave_with_scrub():

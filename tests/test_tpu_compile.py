@@ -66,13 +66,13 @@ def test_dense_tree_attention_compiles_for_v5e(one_chip):
 
 
 def test_paged_tree_attention_compiles_for_v5e(one_chip):
-    bs, bpl = 64, 8                       # block_size 64, 512 positions
+    bs, bpl, L = 64, 8, 2                 # block_size 64, 512 positions
     nb = 1 + B * bpl
-    _compile(lambda q, k, v, bt, m: paged_tree_attention(
-                 q, k, v, bt, m, interpret=False),
-             one_chip, ((B, T, H, DH), BF16), ((nb, bs, K, DH), BF16),
-             ((nb, bs, K, DH), BF16), ((B, bpl), jnp.int32),
-             ((B, T, bpl * bs), jnp.bool_))
+    _compile(lambda q, k, v, bt, m, layer: paged_tree_attention(
+                 q, k, v, bt, m, layer, interpret=False),
+             one_chip, ((B, T, H, DH), BF16), ((L, nb, bs, K * DH), BF16),
+             ((L, nb, bs, K * DH), BF16), ((B, bpl), jnp.int32),
+             ((B, T, bpl * bs), jnp.bool_), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("S", [128, 2048])
@@ -106,20 +106,21 @@ def _canonical_hlo(text: str) -> str:
                   "\n".join(lines))
 
 
-def _compile_step_and_suffix(one_chip):
-    """The paged Pallas fused step and a suffix prefill at qwen2-1.5b's
-    attention widths (one layer), compiled for the described chip."""
+def _compile_step_and_suffix(one_chip, n_layers=1, nb=9, lanes=2,
+                             prefill_len=64, text=True):
+    """The paged Pallas fused step and the largest suffix-prefill bucket at
+    qwen2-1.5b's attention widths, compiled for the described chip."""
     from repro.models import transformer as tx
     from repro.serving.session import make_session_fns
-    cfg = tx.TransformerConfig(n_layers=1, d_model=H * DH, n_heads=H,
+    cfg = tx.TransformerConfig(n_layers=n_layers, d_model=H * DH, n_heads=H,
                                n_kv_heads=K, d_ff=256, vocab_size=512,
                                qkv_bias=True, max_seq_len=256,
                                dtype="bfloat16", param_dtype="bfloat16")
-    lanes, bs, nb = 2, 64, 9
+    bs = 64
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(lambda: tx.init_params(cfg, jax.random.key(0))))
-    fns = make_session_fns(cfg, params, slots=T, prefill_len=64,
+    fns = make_session_fns(cfg, params, slots=T, prefill_len=prefill_len,
                            backend="pallas", kv_layout="paged",
                            block_size=bs, n_blocks=nb, sampling="greedy")
 
@@ -130,16 +131,18 @@ def _compile_step_and_suffix(one_chip):
         return {"greedy": sd((n,), jnp.bool_), "temp": sd((n,), jnp.float32),
                 "seed": sd((n,), jnp.uint32)}
     i32 = jnp.int32
-    cache = {"k": sd((1, nb, bs, K, DH), BF16),
-             "v": sd((1, nb, bs, K, DH), BF16),
+    cache = {"k": sd((n_layers, nb, bs, K * DH), BF16),
+             "v": sd((n_layers, nb, bs, K * DH), BF16),
              "block_tables": sd((lanes, 256 // bs), i32)}
     step = fns.fused_step._jitted.lower(
         params, cache, sd((lanes,), i32), sd((lanes, T), i32),
         sd((lanes, T), i32), sd((lanes, T, T), jnp.bool_),
         sd((lanes, T), i32), sd((lanes,), i32), lp(lanes)).compile()
     suffix = fns.prefill_suffix._jitted.lower(
-        params, cache, sd((), i32), sd((1, 64), i32), sd((1,), i32),
-        sd((1,), i32), lp(1)).compile()
+        params, cache, sd((), i32), sd((1, fns.suffix_buckets[-1]), i32),
+        sd((1,), i32), sd((1,), i32), lp(1)).compile()
+    if not text:
+        return step, suffix
     return [c.as_text() for c in (step, suffix)]
 
 
@@ -168,6 +171,66 @@ def test_named_scopes_are_metadata_only(one_chip, monkeypatch):
     assert not re.search(r'op_name="[^"]*/kv_pool/', plain[0])
     for a, b in zip(scoped, plain):
         assert _canonical_hlo(a) == _canonical_hlo(b)
+
+
+# HLO instruction: name = dtype[dims]{layout} opcode(
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                    r"(?:\{[^}]*\})? ([\w\-]+)\(%?([\w.\-]*)")
+_COPY_START = re.compile(r"^\s*%?(copy-start[\w.]*) = \((\w+\[[^\]]*\]"
+                         r"\{[^}]*\}), (\w+\[[^\]]*\]\{[^}]*\})")
+_POOL_COPIES = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape")
+
+
+def _pool_copies(text: str, sizes) -> list:
+    """Instructions (or fusions named after them) that copy, slice, write
+    back or relayout an array of one of ``sizes`` elements.  Asynchronous
+    moves between HBM and VMEM (memory space S(1)) are left out: they are
+    the compiler staging an array that fits VMEM at the test's size, which
+    no deployment's pool does."""
+    staged = set()
+    for line in text.splitlines():
+        m = _COPY_START.match(line)
+        if m and ("S(1)" in m.group(2)) != ("S(1)" in m.group(3)):
+            staged.add(m.group(1))
+    found = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, _, dims, op, arg = m.groups()
+        if op == "copy-done" and arg in staged:
+            continue
+        n = int(np.prod([int(d) for d in dims.split(",") if d]))
+        kind = op if op != "fusion" else name
+        if n in sizes and any(kind.startswith(c) or f"_{c}" in kind
+                              for c in _POOL_COPIES):
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_paged_pool_is_updated_in_place_for_v5e(one_chip, monkeypatch):
+    """The fused step and the largest suffix-prefill bucket carry the paged
+    pool through the layer scan and write it in place: compiled for the
+    described chip (2 layers), neither holds a copy, slice, write-back or
+    relayout of the pool or of one layer of it, and their temporaries are
+    a small fraction of the pool.  512 blocks (67 MB): at 64 the compiler
+    keeps the whole pool in VMEM and the temporaries cannot tell."""
+    import repro.kernels.flash_prefill.ops as fp_ops
+    import repro.kernels.tree_attention.paged as ta_paged
+    for mod in (fp_ops, ta_paged):      # the Mosaic kernels, not interpret
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    L, nb, bs = 2, 512, 64
+    layer = nb * bs * K * DH
+    pool_bytes = 2 * L * layer * 2                  # K and V, bf16
+    for compiled in _compile_step_and_suffix(one_chip, n_layers=L, nb=nb,
+                                             lanes=4, prefill_len=256,
+                                             text=False):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        copies = _pool_copies(text, {layer, L * layer})
+        assert not copies, copies
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.05 * pool_bytes, (temp, pool_bytes)
 
 
 def test_fused_step_takes_weights_as_arguments():
