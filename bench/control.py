@@ -2,15 +2,16 @@
 the chip.
 
     python3 bench/control.py --workload <name> --seeds 11 12 13 ... \
-        --seconds 10 [--controls int8,fp8] [--rates 1 2 3] \
+        --seconds 10 [--controls int8,fp8] [--rates 1 2 3 [--paired]] \
         [--out chiprun_out/x.json]
 
-For each rate (default: the cell's own) and seed, in one process (the
-programs compile once): set up, run the cell's load for ``--seconds``, then
-read the widest logit gap of the served tokens against the float32
-reference (the sound reading) and, at the same positions, the widest gap of
-each lower-precision control's first choice (the reading a precision cut
-would give), beside the run's end-to-end numbers.  A sweep over ``--rates``
+For each rate (default: the cell's own) and seed, or with ``--paired``
+each rate with its own seed, in one process (the programs compile once):
+set up, run the cell's load for ``--seconds``, then read the widest logit
+gap of the served tokens against the float32 reference (the sound
+reading) and, at the same positions, the widest gap of each
+lower-precision control's first choice (the reading a precision cut would
+give), beside the run's end-to-end numbers.  A sweep over ``--rates``
 finds the highest rate the cell sustains.  The benchmark's own runs never
 run this.
 """
@@ -38,6 +39,8 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--controls", default="int8,fp8")
     ap.add_argument("--rates", type=float, nargs="*", default=None)
+    ap.add_argument("--paired", action="store_true",
+                    help="the i-th rate with the i-th seed")
     ap.add_argument("--out", default=None)
     ap.add_argument("--cpu-rehearsal", action="store_true")
     args = ap.parse_args()
@@ -47,7 +50,13 @@ def main() -> int:
     controls = tuple(c for c in args.controls.split(",") if c)
     rows = []
     rates = args.rates or [cell.settings["load"]["rate"]]
-    for rate, seed in [(r, s) for r in rates for s in args.seeds]:
+    if args.paired:
+        if len(rates) != len(args.seeds):
+            ap.error("--paired needs as many seeds as rates")
+        runs = list(zip(rates, args.seeds))
+    else:
+        runs = [(r, s) for r in rates for s in args.seeds]
+    for rate, seed in runs:
         cell.settings["load"] = dict(cell.settings["load"], rate=rate)
         res = run_once(cell, seed, args.seconds, False, devs, clock,
                        time.perf_counter(), rehearse=args.cpu_rehearsal,

@@ -39,8 +39,9 @@ class Probe:
     PROGRAMS = ("fused_step", "prefill", "prefill_into_slot",
                 "prefill_suffix", "copy_block")
 
-    def __init__(self, shape: Dict[str, Any], pad_id: int,
+    def __init__(self, family, shape: Dict[str, Any], pad_id: int,
                  peaks: Optional[Dict[str, Any]] = None):
+        self.family = family
         self.shape = shape
         self.pad_id = pad_id
         self.peaks = peaks
@@ -49,33 +50,33 @@ class Probe:
         self.calls: Dict[str, int] = {}
 
     def _add(self, prog: str, w: Dict[str, float]) -> None:
-        if self.peaks and "attn_flops" in w:
-            w = dict(w, attn_roof_s=flops.roofline_seconds(
-                w["attn_flops"], w["attn_bytes"], self.peaks))
+        if self.peaks:
+            w = dict(w, **flops.rooflines(w, self.peaks))
         acc = self.work.setdefault(prog, {})
         for k, v in w.items():
             acc[k] = acc.get(k, 0.0) + float(v)
         self.calls[prog] = self.calls.get(prog, 0) + 1
 
     def _count(self, prog: str, args) -> None:
-        s = self.shape
+        s, fam = self.shape, self.family
         if prog == "fused_step":
             _, lens, _, _, mask, _, n_live = args[:7]
             lens, n_live = np.asarray(lens), np.asarray(n_live)
             anc = np.asarray(mask).sum(-1)                   # (B, W)
             lanes = [(int(lens[b]), anc[b, :int(n_live[b])].tolist())
                      for b in range(len(n_live)) if n_live[b] > 0]
-            self._add(prog, flops.step_work(s, lanes))
+            self._add(prog, fam.step_work(s, lanes))
         elif prog == "prefill":
             tokens, lens = np.asarray(args[0]), np.asarray(args[1])
             for b in range(tokens.shape[0]):
                 if tokens[b, 0] != self.pad_id:
-                    self._add(prog, flops.prefill_work(s, int(lens[b])))
+                    self._add(prog, fam.prefill_work(s, int(lens[b])))
         elif prog == "prefill_into_slot":
-            self._add(prog, flops.prefill_work(s, int(np.asarray(args[3])[0])))
+            self._add(prog, fam.prefill_work(
+                s, int(np.asarray(args[3])[0])))
         elif prog == "prefill_suffix":
             n = int(np.asarray(args[2]).shape[1])
-            self._add(prog, flops.prefill_work(s, n, int(args[3])))
+            self._add(prog, fam.prefill_work(s, n, int(args[3])))
         else:
             self._add(prog, {})
 

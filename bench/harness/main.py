@@ -32,7 +32,7 @@ DRAIN_S = 60.0      # in-flight requests finish within this after the window
 SETUP_S = 900.0     # a cold first run compiles every program in set-up
 
 # The CPU rehearsal: any cell's engine, traffic and check at a size the CPU
-# serves in seconds (widths: harness.model.REHEARSAL_WIDTHS).
+# serves in seconds (widths: the family's REHEARSAL_WIDTHS).
 REHEARSAL: Dict[str, Any] = {
     "lanes": 4, "prefill_len": 160, "context": 256, "block_size": 16,
     "n_blocks": 96, "traffic_scale": 0.125, "warmup": {"requests": 8},
@@ -105,11 +105,18 @@ def engine_config(settings: Dict[str, Any]):
 
 
 def params_for(ecfg, namespace: str = ""):
+    """Each request's ``SamplingParams``: its draft policy scoped to the
+    request's tenant namespace, or to ``namespace`` where it has none."""
     import dataclasses
     from repro.core.request import SamplingParams
-    policy = dataclasses.replace(ecfg.draft_policy, namespace=namespace)
+    policies: Dict[str, Any] = {}
 
     def fn(req):
+        ns = req.namespace or namespace
+        policy = policies.get(ns)
+        if policy is None:
+            policy = policies[ns] = dataclasses.replace(ecfg.draft_policy,
+                                                        namespace=ns)
         return SamplingParams(max_new_tokens=int(req.max_new), draft=policy)
     return fn
 
@@ -118,8 +125,9 @@ def warm_programs(drv, ecfg, fns, vocab: int, seed: int, deadline: float
                   ) -> None:
     """Run, under a namespace of their own, requests that reach every
     program the cell's traffic can use: the first cohort's prefill, a slot
-    prefill, a prefix-hit suffix prefill in every suffix bucket, and a
-    copy-on-write fork of a part-matched block."""
+    prefill, a prefix-hit suffix prefill in every suffix bucket, a
+    copy-on-write fork of a part-matched block, and steps whose block
+    tables come from the host."""
     from harness.traffic import Request, stream_rng
     rng = stream_rng(seed, 90)
     plen, bs = ecfg.prefill_len, ecfg.block_size
@@ -146,6 +154,12 @@ def warm_programs(drv, ecfg, fns, vocab: int, seed: int, deadline: float
                              2))
     recs = [drv.submit(r, drv.clock(), params) for r in later]
     drv.drain(recs, deadline)
+    # a step after a lane's table changed on the host (here: its neighbour
+    # retired, and nothing was admitted since) takes the tables from the
+    # host, and compiles apart from one whose tables a program returned
+    pair = [Request(rand(bs), 48), Request(rand(bs), 2)]
+    recs = [drv.submit(r, drv.clock(), params) for r in pair]
+    drv.drain(recs, deadline)
 
 
 def main(argv: List[str], t_process: float) -> int:
@@ -168,13 +182,28 @@ def main(argv: List[str], t_process: float) -> int:
     return 0
 
 
+def side_by_side(fn, items) -> List[Any]:
+    """``fn`` of each item, one thread each where there are several: each
+    chip compiles or loads its own executables, and the compiles overlap."""
+    if len(items) == 1:
+        return [fn(items[0])]
+    from concurrent.futures import ThreadPoolExecutor
+    with ThreadPoolExecutor(len(items)) as pool:
+        return list(pool.map(fn, items))
+
+
 def start_jax() -> CompileClock:
     """Compile cache where the program keeps it; every program cached."""
     import jax
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    log(f"bench: compile cache {cache_dir}")
+    # a cell on four chips holds each program once per chip: under a size
+    # cap the cache evicts one chip's programs for the next one's, and
+    # every run compiles everything again
+    cap = jax.config.jax_compilation_cache_max_size
+    jax.config.update("jax_compilation_cache_max_size", -1)
+    log(f"bench: compile cache {cache_dir} (size cap was {cap}, now none)")
     return CompileClock()
 
 
@@ -189,20 +218,28 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
 
     from harness import correct, e2e
     from harness.driver import Driver, Probe, Stalls, stat_snapshot
-    from harness.model import (Guide, bias_size, make_bias,
-                               make_weights, program_config, shape_of)
-    from harness.spec import peaks_for, reference_module
+    from harness.fleet import Fleet
+    from harness.model import Guide, bias_size, make_bias
+    from harness.spec import family_module, peaks_for, reference_module
     from harness.traffic import Limits, Mix
 
-    dev = devs[0]
     settings = dict(cell.settings)
     if rehearse:
         settings.update(REHEARSAL)
-    shape = shape_of(cell.config, rehearsal=rehearse)
+    fam = family_module(cell.config)
+    rep_spec = settings.get("replicas")
+    n_rep = int(rep_spec["n"]) if rep_spec else 1
+    if len(devs) < n_rep:
+        raise NoChip(f"{n_rep} replicas need {n_rep} devices, found "
+                     f"{len(devs)}")
+    used = list(devs[:n_rep])
+    dev = used[0]
+    shape = fam.shape_of(cell.config, rehearsal=rehearse)
     context = int(settings.get("context", shape["context"]))
     peaks = None if rehearse else peaks_for(dev.device_kind)
     log(f"bench: {cell.name} seed {seed} on {dev.platform} "
-        f"{dev.device_kind} x{len(devs)}")
+        f"{dev.device_kind} x{len(devs)}, {n_rep} replica(s); family "
+        f"{cell.config.get('family', 'decoder')} {json.dumps(shape)}")
 
     t_phase = time.perf_counter()
 
@@ -213,7 +250,9 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
             f"{clock.seconds:.1f} s compiling)")
         t_phase = now
 
-    weights = make_weights(shape, seed, device=dev)
+    # each replica's own copy, drawn on its own chip from the same seed
+    weights = side_by_side(lambda d: fam.make_weights(shape, seed, device=d),
+                           used)
     phase("weights")
     guide_spec = cell.traffic.get("guide")
     guide, size, bias = None, 0.0, None
@@ -225,18 +264,32 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
             "bias_sigmas", guide_spec["bias_sigmas"])))
         bias = make_bias(guide, size)
     ecfg = engine_config(settings)
-    tcfg = program_config(shape, context=context)
-    fns = build_session_fns(ecfg, tcfg, weights, logits_transform=bias)
-    probe = None
-    if trace:
-        probe = Probe(shape, ecfg.pad_id, peaks)
-        fns = probe.wrap_fns(fns)
-    engine = ServingEngine(fns, ecfg, trie=TrieTree(
-        capacity=int(settings.get("trie_capacity", 0)) or ecfg.lookahead()
-        .trie_capacity))
-    if probe is not None:
-        Probe.annotate_scheduler(engine.scheduler)
-    drv = Driver(engine)
+    tcfg = fam.program_config(shape, context=context)
+    probe = Probe(fam, shape, ecfg.pad_id, peaks) if trace else None
+    trie_capacity = (int(settings.get("trie_capacity", 0))
+                     or ecfg.lookahead().trie_capacity)
+
+    def build(w):
+        fns = build_session_fns(ecfg, tcfg, w, logits_transform=bias)
+        if probe is not None:
+            fns = probe.wrap_fns(fns)
+        engine = ServingEngine(fns, ecfg,
+                               trie=TrieTree(capacity=trie_capacity))
+        if probe is not None:
+            Probe.annotate_scheduler(engine.scheduler)
+        return engine
+
+    fleet = None
+    if rep_spec:
+        fleet = Fleet([lambda w=w: build(w) for w in weights], used,
+                      policy=str(rep_spec.get("policy", "affinity")),
+                      max_queue_depth=int(rep_spec.get("max_queue_depth",
+                                                       8)))
+        engines, front = fleet.engines, fleet
+    else:
+        front = build(weights[0])
+        engines = [front]
+    drv = Driver(front)
     limits = Limits(ecfg.prefill_len, context, ecfg.slots)
     mix = Mix(cell.traffic, seed, shape["vocab"], guide, limits,
               scale=float(settings.get("traffic_scale", 1.0)))
@@ -246,7 +299,12 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
     c_before = (clock.count, clock.seconds)
     phase("engine")
     deadline = time.perf_counter() + SETUP_S
-    warm_programs(drv, ecfg, fns, shape["vocab"], seed, deadline)
+    if fleet is None:
+        warm_programs(drv, ecfg, front.fns, shape["vocab"], seed, deadline)
+    else:
+        side_by_side(lambda p: warm_programs(
+            Driver(p), ecfg, p.engine.fns, shape["vocab"], seed, deadline),
+            fleet.pinned)
     phase("programs warmed")
     load = settings["load"]
     warm = settings.get("warmup", {})
@@ -274,6 +332,10 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
     trace_dir = None
     traced = {"t0": None, "t1": None}
     hooks = []
+
+    def caches():
+        return [e.scheduler.cache for e in engines]
+
     if trace:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
         t_off = min(float(settings.get("trace_offset_s", 5.0)),
@@ -284,7 +346,7 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
 
         def trace_hook(now):
             if not state["on"] and now >= t_start + t_off:
-                jax.block_until_ready(engine.scheduler.cache)
+                jax.block_until_ready(caches())
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0    # host spans are our own
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
@@ -294,18 +356,24 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
             elif state["on"] and not state["off"] and (
                     now >= traced["t0"] + t_len or now >= t_end):
                 probe.active = False
-                jax.block_until_ready(engine.scheduler.cache)
+                jax.block_until_ready(caches())
                 traced["t1"] = time.perf_counter()
                 jax.profiler.stop_trace()
                 state["off"] = True
         hooks.append(trace_hook)
+
+    def snapshot():
+        snap = stat_snapshot(front.stats)
+        if fleet is not None:
+            snap.update(fleet.route_counts())
+        return snap
 
     drv.stalls = Stalls()
     c0 = clock.count
     t_start = time.perf_counter()
     t_end = t_start + seconds
     setup_s = t_start - t_process
-    s0 = stat_snapshot(engine.stats)
+    s0 = snapshot()
     if loop is not None:
         for i, (c, ans, _) in enumerate(ready):
             ready[i] = (c, ans, t_start)
@@ -317,7 +385,7 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
     drv.stalls.close()
     for h in hooks:
         h(t_end + 1e9)
-    s1 = stat_snapshot(engine.stats)
+    s1 = snapshot()
     compiles_in_window = clock.count - c0
     drv.drain(recs, t_close + DRAIN_S)
     for r in recs:
@@ -335,16 +403,30 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
         f"requests")
     log(f"bench: e2e {json.dumps(summary)}")
     log(f"bench: host stalls in the window: {drv.stalls.summary()}")
+    if fleet is not None:
+        log(f"bench: router {fleet.router.policy}: homes "
+            f"{json.dumps(fleet.homes(mix.namespaces))}; placed per "
+            f"replica {fleet.placed()} (warm-up included); in the window "
+            f"routed {s1['routed'] - s0['routed']:.0f}, home "
+            f"{s1['affinity_hits'] - s0['affinity_hits']:.0f}, spilled "
+            f"{s1['spills'] - s0['spills']:.0f}")
 
     mem_peak = None
     if not rehearse:
-        stats = dev.memory_stats() or {}
-        mem_peak = int(stats.get("peak_bytes_in_use", 0)) or None
+        # the fullest chip's peak
+        peaks_in_use = [int((d.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0)) for d in used]
+        mem_peak = max(peaks_in_use) or None
+        if len(used) > 1:
+            log(f"bench: peak HBM per chip {peaks_in_use}")
     counters = {k: s1[k] - s0[k] for k in s0}
     decode_tokens = sum(n for r in recs for (t, n) in r.deltas[1:]
                         if t_start <= t < t_end)
     lanes = ecfg.lanes
-    del engine, drv, fns, wrecs     # warm-up handles hold the engine
+    # the reference reads the first replica's weights (all are the same)
+    weights = weights[0]
+    # warm-up handles hold the engines
+    del front, engines, fleet, drv, wrecs
     gc.unfreeze()
     gc.collect()
 
@@ -355,6 +437,10 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
         trace_sum = summarize(from_profile(trace_dir, traced["t1"]
                                            - traced["t0"]))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        if not rehearse and trace_sum.window_s > 0:
+            idle = {d: round(100.0 * (1.0 - b / trace_sum.window_s), 3)
+                    for d, b in sorted(trace_sum.busy_by_device.items())}
+            log(f"bench: device idle % per chip {json.dumps(idle)}")
 
     # ------------------------------------------------------- correctness
     t_ref = time.perf_counter()
@@ -372,14 +458,24 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
         "attempted": len(recs),
         "failed": failed,
     }
+    from harness.spec import metric_reader
+    run = RunData(shape=shape, peaks=peaks, settings=settings,
+                  counters=counters, decode_tokens=decode_tokens,
+                  probe=probe, trace=trace_sum, lanes=lanes)
     if rehearse:
+        # only what the program counts: no device metric comes from a CPU
+        counted = {m["name"]: metric_reader(m["name"])(run)
+                   for m in cell.per_layer
+                   if m["source"] == "program_counter"}
         result["rehearsal"] = {
-            "platform": dev.platform, "requests": len(recs),
+            "platform": dev.platform, "replicas": n_rep,
+            "requests": len(recs),
             "tokens": sum(r.n_tokens for r in recs),
             "decode_steps": counters["decode_steps"],
             "accepted_per_step": decode_tokens / max(
                 counters["active_lane_steps"], 1),
             "prefix_hit_tokens": counters["prefix_hit_tokens"],
+            "program_counters": counted,
             "compiles_in_window": compiles_in_window,
             "kinds_checked": verdict["kinds"]}
     else:
@@ -390,10 +486,6 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devs,
                 metrics[m["name"]] = {"value": values[m["name"]],
                                       "unit": m["unit"]}
         else:
-            from harness.spec import metric_reader
-            run = RunData(shape=shape, peaks=peaks, settings=settings,
-                          counters=counters, decode_tokens=decode_tokens,
-                          probe=probe, trace=trace_sum, lanes=lanes)
             for m in cell.per_layer:
                 v = metric_reader(m["name"])(run)
                 if v is not None and math.isfinite(v):

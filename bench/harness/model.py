@@ -1,10 +1,9 @@
-"""The model a cell serves: its shape from the configuration file, its
-weights from the seed, and the guide bias its traffic asks for.
+"""What every model family shares: the seed's PRNG key, and the guide
+bias a cell's traffic asks for.
 
-Weights are the benchmark's own: drawn on the device from ``--seed`` in
-one jitted call, in the type they are served in, laid out as the program's
-parameter tree.  The plain reference reads the same arrays; nothing here
-comes from the program except the ``TransformerConfig`` it is described to.
+The family (``bench/families/<family>.py``) gives a configuration's shape,
+its weights and its program configuration; the bias here only needs the
+shape's ``d_model`` and ``init_std``.
 """
 from __future__ import annotations
 
@@ -16,112 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# widths of the CPU rehearsal: every cell's structure (GQA ratio, biases,
-# tied head) at a size the CPU serves in seconds
-REHEARSAL_WIDTHS = {"num_hidden_layers": 2, "hidden_size": 256,
-                    "num_attention_heads": 4, "intermediate_size": 512,
-                    "vocab_size": 4096, "head_dim": 64}
-
-
-def shape_of(config: Dict[str, Any], rehearsal: bool = False
-             ) -> Dict[str, Any]:
-    """Plain sizes of a configuration file (published keys), the cut ones
-    already as the file states them; ``rehearsal`` shrinks the widths."""
-    c = dict(config)
-    heads = int(c["num_attention_heads"])
-    kv = int(c.get("num_key_value_heads", heads))
-    s = {
-        "name": c["name"],
-        "layers": int(c["num_hidden_layers"]),
-        "d_model": int(c["hidden_size"]),
-        "heads": heads,
-        "kv_heads": kv,
-        "head_dim": int(c.get("head_dim")
-                        or int(c["hidden_size"]) // heads),
-        "d_ff": int(c["intermediate_size"]),
-        "vocab": int(c["vocab_size"]),
-        "qkv_bias": bool(c.get("qkv_bias", False)),
-        "tied": bool(c.get("tie_word_embeddings", False)),
-        "rope_theta": float(c["rope_theta"]),
-        "norm_eps": float(c["rms_norm_eps"]),
-        "act": str(c["hidden_act"]),
-        "context": int(c["max_position_embeddings"]),
-        "dtype": str(c.get("torch_dtype", "bfloat16")),
-        "init_std": float(c.get("initializer_range", 0.02)),
-    }
-    if rehearsal:
-        r = REHEARSAL_WIDTHS
-        s.update(layers=r["num_hidden_layers"], d_model=r["hidden_size"],
-                 heads=r["num_attention_heads"],
-                 kv_heads=max(1, kv * r["num_attention_heads"] // heads),
-                 head_dim=r["head_dim"], d_ff=r["intermediate_size"],
-                 vocab=r["vocab_size"])
-    return s
-
-
-def program_config(shape: Dict[str, Any], *, context: int):
-    """The program's ``TransformerConfig`` for these sizes."""
-    from repro.models.transformer import TransformerConfig
-    return TransformerConfig(
-        name=shape["name"], n_layers=shape["layers"],
-        d_model=shape["d_model"], n_heads=shape["heads"],
-        n_kv_heads=shape["kv_heads"], d_ff=shape["d_ff"],
-        vocab_size=shape["vocab"], head_dim=shape["head_dim"],
-        qkv_bias=shape["qkv_bias"], rope_theta=shape["rope_theta"],
-        act=shape["act"], tie_embeddings=shape["tied"],
-        norm_eps=shape["norm_eps"], dtype=shape["dtype"],
-        param_dtype=shape["dtype"], max_seq_len=int(context))
-
 
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key for any whole seed, 64-bit ones included."""
     seed = int(seed)
     key = jax.random.key(seed & 0xFFFFFFFF)
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
-
-
-def make_weights(shape: Dict[str, Any], seed: int,
-                 device: Optional[Any] = None) -> Dict[str, Any]:
-    """Random weights in the served dtype, drawn in one jitted call.
-
-    Every matrix is N(0, initializer_range); norm scales are 1 + N(0, std)
-    and biases N(0, std), so a program that dropped either would differ
-    from the reference."""
-    L, d, H, K = (shape["layers"], shape["d_model"], shape["heads"],
-                  shape["kv_heads"])
-    dh, F, V = shape["head_dim"], shape["d_ff"], shape["vocab"]
-    std, dt = shape["init_std"], jnp.dtype(shape["dtype"])
-    leaves = {
-        "embed": (V, d), "ln_f": (d,),
-        "layers/ln1": (L, d), "layers/ln2": (L, d),
-        "layers/wq": (L, d, H * dh), "layers/wk": (L, d, K * dh),
-        "layers/wv": (L, d, K * dh), "layers/wo": (L, H * dh, d),
-        "layers/w_gate": (L, d, F), "layers/w_up": (L, d, F),
-        "layers/w_down": (L, F, d),
-    }
-    if shape["qkv_bias"]:
-        leaves.update({"layers/bq": (L, H * dh), "layers/bk": (L, K * dh),
-                       "layers/bv": (L, K * dh)})
-    if not shape["tied"]:
-        leaves["lm_head"] = (d, V)
-
-    def draw(key):
-        out: Dict[str, Any] = {"layers": {}}
-        for i, (path, shp) in enumerate(sorted(leaves.items())):
-            x = jax.random.normal(jax.random.fold_in(key, i), shp,
-                                  jnp.float32) * std
-            if path.endswith(("ln1", "ln2", "ln_f")):
-                x = x + 1.0
-            x = x.astype(dt)
-            if path.startswith("layers/"):
-                out["layers"][path.split("/", 1)[1]] = x
-            else:
-                out[path] = x
-        return out
-
-    fn = jax.jit(draw) if device is None else jax.jit(
-        draw, out_shardings=jax.sharding.SingleDeviceSharding(device))
-    return jax.block_until_ready(fn(seed_key(seed)))
 
 
 # ------------------------------------------------------------------- guide

@@ -4,15 +4,35 @@
 configuration and its traffic mix.  Everything else is a file of its own,
 found by that name:
 
-    bench/configs/<config>.json     published sizes, cut, reference module
+    bench/configs/<config>.json     published sizes, cut, family, reference
+    bench/families/<family>.py      the model family (default ``decoder``)
     bench/references/<module>.py    the configuration's plain reference
     bench/traffic/<traffic>.json    parameters of the one traffic generator
-    bench/cells/<workload>.json     engine settings and offered load
+    bench/cells/<workload>.json     engine settings, offered load, replicas
     bench/metrics/<metric>.py       one reader per per-layer metric
     bench/peaks.json                chip peaks keyed by ``device_kind``
 
-A later cell, mix, configuration or metric is added as new files and new
-entries; nothing here needs an edit.
+A later cell, mix, configuration, model family or metric is added as new
+files and new entries; nothing here needs an edit.
+
+A model family is a module that defines, for the configurations that name
+it (``"family": "<module>"``):
+
+    shape_of(config, rehearsal=False) -> shape   plain sizes; ``rehearsal``
+        gives the same structure at widths the CPU serves in seconds.  The
+        harness reads ``name``, ``vocab``, ``context``, ``d_model`` and
+        ``init_std`` (the guide bias); the rest is the family's own.
+    make_weights(shape, seed, device=None) -> weights   the program's
+        parameter tree in the served dtype, from the seed alone
+    program_config(shape, *, context) -> the program's model config
+    step_work(shape, lanes) / prefill_work(shape, n, offset=0) -> work
+        of one fused step (``lanes``: per live lane its cache length and
+        each live slot's ancestor count) and of one prefill: ``flops``,
+        ``rows``, and a ``<k>_flops`` / ``<k>_bytes`` pair for each kernel
+        ``<k>`` it counts, which gets a ``<k>_roof_s``
+
+and its configuration names a plain reference (``"reference"``) with
+``logits_at(shape, weights, tokens, rows, precision)``.
 """
 from __future__ import annotations
 
@@ -64,6 +84,10 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     traffic = _load_json(bench_dir / "traffic" / f"{entry['traffic']}.json")
     traffic.setdefault("name", entry["traffic"])
     settings = _load_json(bench_dir / "cells" / f"{workload}.json")
+    replicas = settings.get("replicas")
+    if replicas and int(replicas["n"]) != int(entry["chips"]):
+        raise ValueError(f"{workload}: {replicas['n']} replicas on "
+                         f"{entry['chips']} chips; a replica takes a chip")
     e2e = [m for m in bench["end_to_end"] if _reports(m, workload)]
     per_layer = [m for m in bench["per_layer"] if _reports(m, workload)]
     return Cell(name=workload, chips=int(entry["chips"]), config=config,
@@ -85,6 +109,13 @@ def metric_reader(name: str, root: Path = ROOT
     mod = _load_module(root / "bench" / "metrics" / f"{name}.py",
                        f"bench_metric_{name.replace('.', '_')}")
     return mod.read
+
+
+def family_module(config: Dict[str, Any], root: Path = ROOT):
+    """The configuration's model family (``bench/families/<family>.py``)."""
+    name = config.get("family", "decoder")
+    return _load_module(root / "bench" / "families" / f"{name}.py",
+                        f"bench_family_{name}")
 
 
 def reference_module(config: Dict[str, Any], root: Path = ROOT):

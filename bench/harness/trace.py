@@ -97,7 +97,8 @@ def label_at(t: float, spans: Sequence[Tuple[str, float, float]]) -> str:
 @dataclass
 class Summary:
     window_s: float
-    busy_s: float
+    busy_s: float          # mean over the chips of each one's busy time
+    busy_by_device: Dict[int, float]
     module_s: Dict[str, float]
     module_calls: Dict[str, int]
     op_s: Dict[Tuple[str, str], float]      # (module, op family) -> s
@@ -126,7 +127,8 @@ def summarize(tr: Trace, top: int = 10) -> Summary:
     for o in tr.ops:
         per_dev.setdefault(o.device, []).append((o.start, o.end))
     n_dev = max(tr.n_devices, len(per_dev), 1)
-    busy = sum(union_seconds(v) for v in per_dev.values()) / n_dev
+    by_dev = {d: union_seconds(v) for d, v in per_dev.items()}
+    busy = sum(by_dev.values()) / n_dev
     module_s: Dict[str, float] = {}
     module_calls: Dict[str, int] = {}
     for m in tr.modules:
@@ -147,7 +149,8 @@ def summarize(tr: Trace, top: int = 10) -> Summary:
     gaps.sort(key=lambda g: g[0] - g[1])
     top_gaps = [[label_at((s + e) / 2, tr.spans), (e - s) * 1e-9]
                 for s, e in gaps[:top]]
-    return Summary(window_s=tr.window_s, busy_s=busy, module_s=module_s,
+    return Summary(window_s=tr.window_s, busy_s=busy, busy_by_device=by_dev,
+                   module_s=module_s,
                    module_calls=module_calls, op_s=op_s, top_ops=top_ops,
                    top_gaps=top_gaps)
 

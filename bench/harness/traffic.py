@@ -10,6 +10,12 @@ sessions, the history and a user turn.  Loops: ``open`` (arrivals due on
 a schedule whatever the server does) or ``closed`` (clients that send the
 next request when the previous answer is complete).
 
+With ``tenants`` (``{"n": 4, "shares": [...], "namespace": "tenant{i}"}``)
+each request belongs to one tenant, drawn by the shares: every tenant has
+its own system prompt and its own document pool, at the mix's sizes, and
+its requests carry the tenant's namespace.  A mix without ``tenants``
+draws what it drew before tenants existed.
+
 Sizes are stratified: a run of N requests takes the N quantiles
 (i + 0.5) / N of each length distribution, of the arrival gaps and of the
 Zipf ranks, in an order that is the same for every seed (the schedule,
@@ -90,6 +96,7 @@ class Request:
     due: float = 0.0              # seconds after the window opens (open loop)
     client: int = -1              # closed loop: which client sent it
     tag: str = ""
+    namespace: str = ""           # the tenant's (the draft trie's scope)
 
 
 @dataclass
@@ -117,38 +124,60 @@ class Mix:
         self.guide = guide
         self.limits = limits
         self.scale = float(scale)
-        rng = stream_rng(seed, 1)
         n_sys = round(int(traffic.get("system_prompt", 0)) * scale)
-        self.system = rng.integers(2, vocab, size=(n_sys,)).tolist()
-        self._docs: Dict[int, List[int]] = {}
+        self.tenants = traffic.get("tenants")
+        # tenant t's streams: those of a mix without tenants, plus 1 + t
+        nt = int(self.tenants["n"]) if self.tenants else 1
+        self._tid = [(1 + t,) if self.tenants else () for t in range(nt)]
+        self.namespaces = ([str(self.tenants["namespace"]).format(i=t)
+                            for t in range(nt)] if self.tenants else [""])
+        self.systems = [stream_rng(seed, 1, *tid).integers(
+            2, vocab, size=(n_sys,)).tolist() for tid in self._tid]
+        self.system = self.systems[0]
+        self._docs: Dict[tuple, List[int]] = {}
         docs = traffic.get("documents")
         if docs:
             pool = int(docs["pool"])
-            # length by popularity rank: the same for every seed
+            # length by popularity rank: the same for every seed and tenant
             lens = quantiles(docs["length"], pool, scale)
             self._doc_len = np.random.default_rng(0).permutation(lens)
             # which document holds each rank: part of the schedule
-            self._doc_of_rank = schedule_rng(2).permutation(pool)
+            self._doc_of_rank = [schedule_rng(2, *tid).permutation(pool)
+                                 for tid in self._tid]
 
     # ----------------------------------------------------------- parts
     def _rand(self, rng: np.random.Generator, n: int) -> List[int]:
         return rng.integers(2, self.vocab, size=(int(n),)).tolist()
 
-    def document(self, rank: int) -> List[int]:
-        """The document of popularity ``rank`` (a guide walk that starts
-        right after the system prompt)."""
-        doc_id = int(self._doc_of_rank[rank])
-        doc = self._docs.get(doc_id)
+    def document(self, rank: int, tenant: int = 0) -> List[int]:
+        """Tenant ``tenant``'s document of popularity ``rank`` (a guide walk
+        that starts right after the system prompt)."""
+        tid = self._tid[tenant]
+        doc_id = int(self._doc_of_rank[tenant][rank])
+        doc = self._docs.get((tenant, doc_id))
         if doc is None:
             n = int(self._doc_len[rank])
-            start = int(schedule_rng(3, doc_id).integers(2, self.vocab))
+            start = int(schedule_rng(3, doc_id, *tid).integers(2, self.vocab))
             if self.guide is not None:
                 doc = guide_walk(self.guide, start, len(self.system),
                                  n).tolist()
             else:
-                doc = self._rand(schedule_rng(3, doc_id), n)
-            self._docs[doc_id] = doc
+                doc = self._rand(schedule_rng(3, doc_id, *tid), n)
+            self._docs[(tenant, doc_id)] = doc
         return doc
+
+    def tenant_of(self, n: int, *stream: int) -> np.ndarray:
+        """Each of n requests' tenant: the shares' stratified counts (largest
+        remainders), in an order that is part of the schedule."""
+        if not self.tenants:
+            return np.zeros((n,), np.int64)
+        w = np.asarray(self.tenants["shares"], np.float64)
+        exact = w / w.sum() * n
+        counts = np.floor(exact).astype(np.int64)
+        rest = np.argsort(-(exact - counts), kind="stable")
+        counts[rest[:n - int(counts.sum())]] += 1
+        return schedule_rng(*stream, 7).permutation(
+            np.repeat(np.arange(len(w)), counts))
 
     def question(self, rng: np.random.Generator, doc: List[int], n: int,
                  pos0: int, where: float) -> List[int]:
@@ -210,12 +239,14 @@ class Mix:
         p_len = (order.permutation(quantiles(t["prompt"]["length"], n,
                                              self.scale))
                  if "prompt" in t else None)
+        tenant = self.tenant_of(n, *stream)
         reqs = []
         for i in range(n):
-            prompt = list(self.system)
+            ti = int(tenant[i])
+            prompt = list(self.systems[ti])
             tag = ""
             if ranks is not None:
-                doc = self.document(int(ranks[i]))
+                doc = self.document(int(ranks[i]), ti)
                 prompt += doc
                 tag = f"rank{int(ranks[i])}"
             if q_len is not None:
@@ -227,7 +258,8 @@ class Mix:
             cut = self.limits.max_seq_len - self.limits.width + 1
             prompt = prompt[:self.limits.prefill_len]
             max_new = max(1, min(max_new, cut - len(prompt)))
-            reqs.append(Request(prompt=prompt, max_new=max_new, tag=tag))
+            reqs.append(Request(prompt=prompt, max_new=max_new, tag=tag,
+                                namespace=self.namespaces[ti]))
         return reqs
 
     def clients(self, n_clients: int, stream: int = 0) -> "ClosedLoop":

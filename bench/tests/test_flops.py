@@ -5,7 +5,10 @@ import os
 import pytest
 
 from harness import flops
-from harness.model import shape_of
+from harness.spec import family_module
+
+dec = family_module({"family": "decoder"})
+shape_of = dec.shape_of
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -20,10 +23,10 @@ def test_qwen2_counts():
     # per layer: q 1536*1536, k and v 1536*256 each, o 1536*1536,
     # MLP 3*1536*8960
     per_layer = 2359296 + 786432 + 2359296 + 41287680
-    assert flops.linear_params(s) == 28 * per_layer == 1310195712
-    assert flops.head_params(s) == 1536 * 151936
+    assert dec.linear_params(s) == 28 * per_layer == 1310195712
+    assert dec.head_params(s) == 1536 * 151936
     # one token through the whole model: 3.09 GFLOP
-    tok = 2 * (flops.linear_params(s) + flops.head_params(s))
+    tok = 2 * (dec.linear_params(s) + dec.head_params(s))
     assert tok == pytest.approx(3.087e9, rel=1e-3)
 
 
@@ -39,17 +42,17 @@ PHI3 = {"name": "phi3-mini-3.8b", "num_hidden_layers": 32,
 def test_phi3_counts():
     s = shape_of(PHI3)
     per_layer = 9437184 + 18874368 + 9437184 + 75497472
-    assert flops.linear_params(s) == 32 * per_layer
-    assert flops.head_params(s) == 3072 * 32064
+    assert dec.linear_params(s) == 32 * per_layer
+    assert dec.head_params(s) == 3072 * 32064
     # K and V of one token, all layers, head size 96: 393,216 bytes
-    assert flops.kv_bytes(s, 1) == 393216
+    assert dec.kv_bytes(s, 1) == 393216
 
 
 def test_step_work_counts_live_rows_only():
     s = shape("qwen2-1.5b")
-    w = flops.step_work(s, [(100, [1, 2, 3]), (7, [1])])
+    w = dec.step_work(s, [(100, [1, 2, 3]), (7, [1])])
     rows, keys = 4, (100 * 3 + 6) + (7 + 1)
-    per_row = 2 * (flops.linear_params(s) + flops.head_params(s))
+    per_row = 2 * (dec.linear_params(s) + dec.head_params(s))
     attn = 4 * 28 * 12 * 128 * keys
     assert w["rows"] == rows
     assert w["attn_flops"] == attn
@@ -61,11 +64,11 @@ def test_step_work_counts_live_rows_only():
 
 def test_prefill_work():
     s = shape_of(PHI3)
-    w = flops.prefill_work(s, 10, offset=64)
+    w = dec.prefill_work(s, 10, offset=64)
     keys = 10 * 64 + 55
     assert w["attn_flops"] == 4 * 32 * 32 * 96 * keys
-    assert w["flops"] == (2 * flops.linear_params(s) * 10
-                          + 2 * flops.head_params(s) + w["attn_flops"])
+    assert w["flops"] == (2 * dec.linear_params(s) * 10
+                          + 2 * dec.head_params(s) + w["attn_flops"])
 
 
 def test_roofline_picks_the_binding_peak():

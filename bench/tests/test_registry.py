@@ -86,3 +86,65 @@ def test_every_listed_metric_has_a_reader():
     for w in bench["workloads"]:
         cell = load_cell(w["name"])
         assert cell.per_layer and cell.end_to_end
+
+
+def add_family(root):
+    """A model family that exists only as a new file (the decoder's source
+    under another name, with three layers in its rehearsal), a
+    configuration that names it, and a cell on it."""
+    src = (root / "bench" / "families" / "decoder.py").read_text()
+    src = src.replace('"num_hidden_layers": 2,', '"num_hidden_layers": 3,')
+    (root / "bench" / "families" / "decoder3.py").write_text(src)
+    cfg = json.loads((root / "bench" / "configs" /
+                      "qwen2-1.5b.json").read_text())
+    cfg["family"] = "decoder3"
+    (root / "bench" / "configs" / "qwen2-1.5b-d3.json").write_text(
+        json.dumps(cfg))
+    (root / "bench" / "cells" / "qwen2-1.5b-d3.rag.json").write_text(
+        (root / "bench" / "cells" / "qwen2-1.5b.rag.json").read_text())
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(bench["configs"][0], name="qwen2-1.5b-d3",
+                                 file="bench/configs/qwen2-1.5b-d3.json"))
+    bench["workloads"].append({
+        "name": "qwen2-1.5b-d3.rag", "config": "qwen2-1.5b-d3",
+        "traffic": "rag", "chips": 1, "why": "throwaway"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_new_family_found_by_files_alone(checkout):
+    """A configuration whose family lives in a new file loads through
+    ``load_cell`` and finishes a CPU rehearsal through the family."""
+    import subprocess
+    import sys
+
+    from harness.spec import family_module
+    add_family(checkout)
+    os.symlink(os.path.join(ROOT, "src"), checkout / "src")
+    cell = load_cell("qwen2-1.5b-d3.rag", root=checkout)
+    fam = family_module(cell.config, root=checkout)
+    assert fam.shape_of(cell.config, rehearsal=True)["layers"] == 3
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "qwen2-1.5b-d3.rag",
+         "--seed", "4000000023", "--seconds", "3", "--trace", "0",
+         "--cpu-rehearsal"], cwd=checkout, env=env, capture_output=True,
+        text=True, timeout=900)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = next(x for x in p.stderr.splitlines() if "family decoder3 {" in x)
+    assert json.loads(line.split("family decoder3 ", 1)[1])["layers"] == 3
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+
+
+def test_replicas_must_match_the_chips(checkout):
+    bench = json.loads((checkout / "BENCHMARK.json").read_text())
+    fleet = [w for w in bench["workloads"]
+             if w["name"] == "qwen2-1.5b.fleet4.rag"]
+    assert fleet and fleet[0]["chips"] == 4
+    assert load_cell("qwen2-1.5b.fleet4.rag", root=checkout).settings[
+        "replicas"]["n"] == 4
+    fleet[0]["chips"] = 1
+    (checkout / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(ValueError):
+        load_cell("qwen2-1.5b.fleet4.rag", root=checkout)

@@ -169,3 +169,82 @@ def test_sessions_grow_and_fit():
             prev[c] = list(range(2, 2 + req.max_new))
             last[c] = req.prompt
     assert "turn1" in seen_turns and "turn3" in seen_turns
+
+
+def stream_digest(reqs, due=True):
+    import hashlib
+    h = hashlib.sha256()
+    for r in reqs:
+        row = [r.prompt, r.max_new] + ([round(r.due, 12)] if due else [])
+        h.update(json.dumps(row + [r.tag]).encode())
+    return h.hexdigest()
+
+
+def test_a_mix_without_tenants_draws_as_before():
+    """The rag stream of one seed, window and warm-up, hashed before the
+    mix had tenants: the same requests in the same order."""
+    t = mix_file("rag")
+    guide = Guide.of(t["guide"], 151936)
+    m = Mix(t, 9140000031, 151936, guide, Limits(1152, 1536, 33))
+    reqs = m.open_loop(3.2, 50.0)
+    assert len(reqs) == 160
+    assert stream_digest(reqs) == (
+        "ab98e845df2cffd6418c63577683a7353af26df68ccbfbe2f303a4ce82df9a3e")
+    assert stream_digest(m.requests(32, 30), due=False) == (
+        "551c165cfbfe455e9e058575adffaff4e4be8f75adb40209c95cf71d24b62211")
+    assert {r.namespace for r in reqs} == {""}
+
+
+def test_tenants_have_their_own_pools_and_prompts():
+    t = mix_file("rag4")
+    assert {k: v for k, v in t.items() if k not in ("tenants", "about")} \
+        == {k: v for k, v in mix_file("rag").items() if k != "about"}
+    guide = Guide.of(t["guide"], 151936)
+    m = Mix(t, SEED, 151936, guide, Limits(1152, 1536, 33))
+    reqs = m.open_loop(12.0, 20.0)
+    names = [f"tenant{i}" for i in range(4)]
+    assert m.namespaces == names
+    # the shares, stratified: 60 requests from each of the four
+    assert sorted({r.namespace for r in reqs}) == names
+    assert all(sum(r.namespace == n for r in reqs) == 60 for n in names)
+    sys_len = 64
+    assert len({tuple(s) for s in m.systems}) == 4
+    assert all(len(s) == sys_len for s in m.systems)
+    for r in reqs:
+        ti = names.index(r.namespace)
+        assert r.prompt[:sys_len] == m.systems[ti]
+        doc = m.document(int(r.tag[4:]), ti)
+        assert r.prompt[sys_len:sys_len + len(doc)] == doc
+    # the same rank is another document in another tenant's pool, of the
+    # same length (the sizes are the mix's)
+    for rank in (0, 1, 50):
+        docs = [m.document(rank, ti) for ti in range(4)]
+        assert len({len(d) for d in docs}) == 1
+        assert len({tuple(d[:16]) for d in docs}) == 4
+    # the sizes and arrivals are the mix's own, whatever the tenants
+    plain = rag_mix()[0].open_loop(12.0, 20.0)
+    assert [(r.max_new, r.tag, len(r.prompt), r.due) for r in reqs] == \
+        [(r.max_new, r.tag, len(r.prompt), r.due) for r in plain]
+    # one schedule for every seed: the same tenants in the same order
+    other = Mix(t, 7, 151936, guide, Limits(1152, 1536, 33))
+    assert [r.namespace for r in other.open_loop(12.0, 20.0)] == \
+        [r.namespace for r in reqs]
+
+
+def test_requests_carry_their_tenants_namespace():
+    from harness.main import params_for
+
+    class Cfg:
+        from repro.core.draft_sources import DraftPolicy
+        draft_policy = DraftPolicy(sources=("trie", "prompt_copy"))
+    fn = params_for(Cfg(), "bench.warmup")
+    t = mix_file("rag4")
+    m = Mix(t, SEED, 151936, Guide.of(t["guide"], 151936),
+            Limits(1152, 1536, 33))
+    for r in m.requests(8, 30):
+        p = fn(r)
+        assert p.draft.namespace == r.namespace != ""
+        assert p.draft.sources == ("trie", "prompt_copy")
+        assert p.max_new_tokens == r.max_new
+    plain = rag_mix()[0].requests(2, 30)[0]
+    assert fn(plain).draft.namespace == "bench.warmup"
